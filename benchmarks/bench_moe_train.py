@@ -34,7 +34,7 @@ from repro.data import PipelineConfig, ShardedTokenPipeline
 from repro.kernels.cg_dispatch import cg_dispatch
 from repro.kernels.ref import ref_cg_dispatch
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import enter_mesh, make_smoke_mesh
+from repro.launch.mesh import make_smoke_mesh
 from repro.models import model_zoo as zoo
 
 from .common import fmt, record, table
@@ -68,7 +68,7 @@ def _train_cell(arch: str, router: str, skew: float, n_steps: int,
                                 total_steps=n_steps)
     pipe = ShardedTokenPipeline(PipelineConfig(
         vocab=cfg.vocab, seq_len=seq, global_batch=batch))
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = zoo.init_params(cfg, jax.random.PRNGKey(0))
         opt_state = optim.init(params)
         train_step = jax.jit(steps_mod.make_train_step(cfg, opt_cfg))

@@ -1,10 +1,9 @@
-"""Multi-host serving on a simulated mesh — the scale-out gate.
+"""Multi-host serving on a device mesh — the scale-out gate.
 
-Routes a keyed Zipf stream across ``--hosts`` (default 8) simulated
-hosts: source lanes live on a 1-D ``("sources",)`` device mesh
-(``--xla_force_host_platform_device_count``, the same trick
-``launch/dryrun.py`` uses), per-block routing runs under ``shard_map``
-and the delta-merge is a ``jax.lax.psum`` (``repro.kernels.mesh``).
+Routes a keyed Zipf stream across ``--hosts`` devices (default: every
+device this process has): source lanes live on a 1-D ``("sources",)``
+device mesh, per-block routing runs under ``shard_map`` and the
+delta-merge is a ``jax.lax.psum`` (``repro.kernels.mesh``).
 
 Three measurements, all recorded into BENCH_results.json:
 
@@ -22,10 +21,12 @@ Three measurements, all recorded into BENCH_results.json:
   mid-run; ``submitted == served + in_flight`` is asserted at every
   tick and the drain must end with zero in flight, zero dropped.
 
-When the current process has too few devices (the default CI bench job
-runs single-device), the whole measurement re-execs as a subprocess
-with the device-count flag set — results come back as JSON and are
-recorded in the parent's BENCH_results.json.
+The measurement runs in this process on the devices it has: one
+process per chip, so nothing here starts a child that would need the
+chip this process already holds. Asking for more hosts than devices
+fails. To simulate hosts on the CPU, set the device count from outside,
+before JAX starts:
+``XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu``.
 
 ``--demo`` routes a paper-scale stream (2^21 messages, 8192 bins)
 across the mesh and prints per-host lane stats — the §V-C topology at
@@ -34,14 +35,9 @@ deployment size.
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import subprocess
-import sys
 
 from .common import fmt, record, table, time_median
-
-_MARK = "MULTIHOST_RESULT_JSON:"
 
 
 def _workload(quick: bool, demo: bool):
@@ -53,7 +49,7 @@ def _workload(quick: bool, demo: bool):
 
 
 # ---------------------------------------------------------------------------
-# In-process measurement (needs len(jax.devices()) >= hosts)
+# Measurement
 # ---------------------------------------------------------------------------
 
 def _measure(hosts: int, quick: bool, demo: bool) -> list[dict]:
@@ -63,7 +59,7 @@ def _measure(hosts: int, quick: bool, demo: bool) -> list[dict]:
 
     from repro.kernels.mesh import mesh_porc_multisource
     from repro.kernels.ref import ref_porc_multisource
-    from repro.launch.mesh import enter_mesh, make_source_mesh
+    from repro.launch.mesh import make_source_mesh
     from repro.runtime.chaos import ChaosSchedule
     from repro.serve import MeshCGRequestRouter, ServingEngine
 
@@ -88,7 +84,7 @@ def _measure(hosts: int, quick: bool, demo: bool) -> list[dict]:
                      n_msgs=int(ke.shape[0]), exact=True))
 
     # -- throughput: sharded vs vmapped single-host on the same stream
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         t_mesh, _ = time_median(lambda: mesh_porc_multisource(
             keys, w["n_bins"], mesh, n_sources=S, sync_every=1,
             block=w["block"], chunk=w["chunk"]))
@@ -144,47 +140,18 @@ def _measure(hosts: int, quick: bool, demo: bool) -> list[dict]:
 # Driver
 # ---------------------------------------------------------------------------
 
-def _via_subprocess(hosts: int, quick: bool, demo: bool) -> list[dict]:
-    """Re-exec with the device-count flag (it must be set before jax
-    initializes, which in this process it already has)."""
-    import repro
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={hosts}"
-                        ).strip()
-    src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (src, env.get("PYTHONPATH")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.bench_multihost", "--child",
-           "--hosts", str(hosts)]
-    if quick:
-        cmd.append("--quick")
-    if demo:
-        cmd.append("--demo")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=1800)
-    for line in out.stdout.splitlines():
-        if not line.startswith(_MARK):
-            print(line)
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr)
-        raise RuntimeError(f"multihost child failed (rc={out.returncode})")
-    payload = [ln for ln in out.stdout.splitlines()
-               if ln.startswith(_MARK)]
-    if not payload:
-        raise RuntimeError("multihost child produced no result payload")
-    return json.loads(payload[-1][len(_MARK):])
-
-
 def run(quick: bool = False, gate: bool = False, demo: bool = False,
-        hosts: int = 8, min_ratio: float | None = None):
+        hosts: int | None = None, min_ratio: float | None = None):
     import jax
-    if len(jax.devices()) >= hosts:
-        rows = _measure(hosts, quick, demo)
-    else:
-        print(f"{len(jax.devices())} device(s) in-process — re-execing "
-              f"with {hosts} simulated hosts")
-        rows = _via_subprocess(hosts, quick, demo)
+    have = len(jax.devices())
+    hosts = have if hosts is None else hosts
+    if hosts > have:
+        raise RuntimeError(
+            f"--hosts {hosts} needs {hosts} devices; this process has "
+            f"{have} ({jax.devices()[0].device_kind}). On the CPU, set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={hosts} "
+            f"before starting.")
+    rows = _measure(hosts, quick, demo)
     for r in rows:
         record("multihost", **r)
 
@@ -193,7 +160,7 @@ def run(quick: bool = False, gate: bool = False, demo: bool = False,
     ratio = thr["sharded"]["ratio"]
     cores = thr["sharded"].get("cpu_cores") or 1
     print(table(
-        f"multi-host serving on {hosts} simulated hosts",
+        f"multi-host serving on {hosts} x {jax.devices()[0].device_kind}",
         ["scenario", "msgs/sec", "ratio", "dropped"],
         [["sharded", fmt(thr["sharded"]["msgs_per_sec"], 0),
           fmt(ratio, 2), "-"],
@@ -222,15 +189,10 @@ def main():
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--gate", action="store_true")
     ap.add_argument("--demo", action="store_true")
-    ap.add_argument("--hosts", type=int, default=8)
+    ap.add_argument("--hosts", type=int, default=None,
+                    help="mesh size (default: every device present)")
     ap.add_argument("--min-ratio", type=float, default=None)
-    ap.add_argument("--child", action="store_true",
-                    help="internal: emit rows as JSON for the parent")
     args = ap.parse_args()
-    if args.child:
-        rows = _measure(args.hosts, args.quick, args.demo)
-        print(_MARK + json.dumps(rows))
-        return
     run(quick=args.quick, gate=args.gate, demo=args.demo,
         hosts=args.hosts, min_ratio=args.min_ratio)
 

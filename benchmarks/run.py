@@ -17,6 +17,8 @@ import time
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (bench_deployment, bench_dynamic, bench_epsilon,
                bench_failures, bench_heterogeneous, bench_hh_probing,
                bench_moe_router, bench_moe_train, bench_multihost,
@@ -43,7 +45,7 @@ ALL = [
                                                # topk vs CG x uniform vs
                                                # skewed expert capacity
     ("multihost", bench_multihost),            # mesh-sharded serving
-                                               # across simulated hosts
+                                               # across the devices present
     ("roofline", roofline),                    # §Roofline
 ]
 
@@ -55,6 +57,7 @@ def main():
     ap.add_argument("--out", default="BENCH_results.json",
                     help="results JSON path ('' disables)")
     args = ap.parse_args()
+    enable_compile_cache()
     names = [n for n, _ in ALL]
     if args.only and args.only not in names:
         raise SystemExit(f"unknown --only {args.only!r}; "

@@ -306,7 +306,7 @@ def _route_slot(cfg: CGConfig, vw_load, t_offset, sg_ptr, sketch, keys):
         vw, state = ref_porc_multisource(
             keys, V, cfg.n_sources, sync_every=cfg.sync_every,
             block=cfg.block_size, eps=cfg.eps, state=state, policy=policy,
-            engine=resolve_engine(cfg.engine))
+            engine=resolve_engine(cfg.engine, policy))
         sketch = (None if state.sketch_base is None
                   else state.sketch_base + state.sketch_delta.sum(0))
         return state.base + state.delta.sum(0), sketch, vw
@@ -321,7 +321,7 @@ def _route_slot(cfg: CGConfig, vw_load, t_offset, sg_ptr, sketch, keys):
         state = PorcState(load=vw_load, routed=t_offset, sketch=sketch)
         vw, state = ref_porc_route(keys, V, block=cfg.block_size,
                                    eps=cfg.eps, state=state, policy=policy,
-                                   engine=resolve_engine(cfg.engine))
+                                   engine=resolve_engine(cfg.engine, policy))
         return state.load, state.sketch, vw
 
     # PoRC (Alg. 1) continuing across slots: capacity uses global time.

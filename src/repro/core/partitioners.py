@@ -240,7 +240,7 @@ def power_of_random_choices_multisource(keys: jnp.ndarray, n_bins: int,
     assign, _ = ref_porc_multisource(keys, n_bins, n_sources,
                                      sync_every=sync_every, block=block,
                                      eps=eps, policy=hh,
-                                     engine=resolve_engine(engine))
+                                     engine=resolve_engine(engine, hh))
     return assign
 
 
@@ -254,7 +254,8 @@ def _hh_choices(keys: jnp.ndarray, n_bins: int, scheme: str, eps: float,
     from repro.kernels.ref import HHPolicy, ref_porc_route
     policy = HHPolicy(scheme=scheme) if hh is None else hh._replace(scheme=scheme)
     assign, _ = ref_porc_route(keys, n_bins, block=block, eps=eps,
-                               policy=policy, engine=resolve_engine(engine))
+                               policy=policy,
+                               engine=resolve_engine(engine, policy))
     return assign
 
 

@@ -13,8 +13,11 @@ Two knobs resolve here:
   ``"pallas"`` the Pallas block engine, ``"auto"`` picks Pallas on TPU
   and jnp elsewhere (on CPU the interpreted kernel is strictly slower
   than the jnp scan — same math, per-op interpreter overhead — so auto
-  never pays it). The internal names ``"snapshot"``/``"strict"`` pass
-  through for callers addressing ``kernels.ref`` directly.
+  never pays it). Heavy-hitter policy traffic is the exception: its
+  kernel body does not lower to Mosaic, so ``"auto"`` with a policy
+  names the jnp engine on every platform. The internal names
+  ``"snapshot"``/``"strict"`` pass through for callers addressing
+  ``kernels.ref`` directly.
 """
 from __future__ import annotations
 
@@ -30,12 +33,13 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return not on_tpu() if interpret is None else interpret
 
 
-def resolve_engine(engine: str) -> str:
-    """Map an engine knob to the concrete block engine to run."""
+def resolve_engine(engine: str, policy=None) -> str:
+    """Map an engine knob to the concrete block engine to run for
+    traffic routed with ``policy`` (an ``HHPolicy`` or None)."""
     if engine in ("ref", "jnp"):
         return "snapshot"
     if engine == "auto":
-        return "pallas" if on_tpu() else "snapshot"
+        return "pallas" if on_tpu() and policy is None else "snapshot"
     if engine in ("snapshot", "strict", "pallas"):
         return engine
     raise ValueError(

@@ -20,7 +20,7 @@ cannot close over concrete device arrays):
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -58,21 +58,80 @@ def view_cap(eps: float, n_bins: int, mass, lookahead: float):
     return (1.0 + eps) * (mass + lookahead) / n_bins
 
 
+def count_sum(x, axis=None, keepdims: bool = False):
+    """Sum of per-bin message counts, independent of reduction order.
+
+    A float32 sum of counts whose total passes 2^24 rounds, and XLA,
+    Mosaic and a cross-device psum each add in their own order — so the
+    jnp, Pallas and mesh engines would derive different local-view
+    capacities from the same loads. Here each count splits into a
+    multiple of 2^16 and a remainder, both summed as exact int32; the
+    only rounding is the final conversion. Integer-valued counts (what
+    the engines carry) therefore sum identically everywhere, and below
+    2^24 the result equals the plain float sum. A fractional part (a
+    restored non-integer load) is added back as a float sum.
+    """
+    hi = jnp.floor(x * (1.0 / 65536.0))
+    rest = x - hi * 65536.0                       # exact, in [0, 65536)
+    lo = jnp.floor(rest)
+    total = (jnp.sum(hi.astype(jnp.int32), axis, keepdims=keepdims)
+             .astype(jnp.float32) * 65536.0
+             + jnp.sum(lo.astype(jnp.int32), axis, keepdims=keepdims)
+             .astype(jnp.float32))
+    return total + jnp.sum(rest - lo, axis, keepdims=keepdims)
+
+
+# ---------------------------------------------------------------------------
+# Per-bin table access
+# ---------------------------------------------------------------------------
+
+class Table(NamedTuple):
+    """How block math reads a per-bin vector (load, view).
+
+    The jnp engines index it directly (``GATHER``). A Pallas TPU kernel
+    cannot gather on a vector, so it passes a compare-and-select
+    implementation (``porc_snapshot.ONEHOT``); with one selected element
+    per row both give the same bits.
+    """
+    take: Callable      # (table, idx) -> table[idx]
+    argmin: Callable    # table -> first index of the minimum, int32
+
+
+GATHER = Table(take=lambda t, i: t[i],
+               argmin=lambda t: jnp.argmin(t).astype(jnp.int32))
+
+
+def salt_columns(cand) -> list:
+    """Split a hoisted candidate tensor [..., C] into its C per-salt
+    arrays, the form :func:`snapshot_block` consumes."""
+    return [cand[..., j] for j in range(cand.shape[-1])]
+
+
 # ---------------------------------------------------------------------------
 # Snapshot probing (the plain engine)
 # ---------------------------------------------------------------------------
 
-def snapshot_resolve(load, cap, cand, salts, assign, max_probes):
-    """First under-cap candidate per key, respecting the probe ceiling."""
-    ok = (load[cand] < cap) & (salts <= max_probes)[None, :]
-    first = jnp.argmax(ok, axis=1)
-    pick = jnp.take_along_axis(cand, first[:, None], 1)[:, 0]
-    hit = (assign < 0) & jnp.any(ok, axis=1)
-    return jnp.where(hit, pick, assign)
+def snapshot_resolve(table: Table, load, cap, cands, salts, assign,
+                     max_probes: int):
+    """First under-cap candidate per key, respecting the probe ceiling.
+    ``cands[j]`` (shaped like ``assign``) is every key's candidate at
+    ``salts[j]``; keys already assigned keep their bin."""
+    probes = [(c, s) for c, s in zip(cands, salts)
+              if not (isinstance(s, int) and s > max_probes)]
+    # last salt first, so the first under-cap candidate is written last;
+    # each step reads ``pick`` once (a forward chain reading ``assign``
+    # twice per salt makes XLA's CPU compile time grow steeply in chunk)
+    pick = jnp.full_like(assign, -1)
+    for cand, salt in reversed(probes):
+        ok = table.take(load, cand) < cap
+        if not isinstance(salt, int):
+            ok = ok & (salt <= max_probes)
+        pick = jnp.where(ok, cand, pick)
+    return jnp.where(assign < 0, pick, assign)
 
 
 def snapshot_block(load, cap, kblk, cand0, n_bins: int, block: int,
-                   chunk: int):
+                   chunk: int, table: Table = GATHER):
     """Route one block of keys against a frozen load snapshot.
 
     The single routing semantics shared by ``ref_porc_snapshot`` (one
@@ -81,32 +140,33 @@ def snapshot_block(load, cap, kblk, cand0, n_bins: int, block: int,
     salted-probe chain against ``load`` and stops at the first bin below
     ``cap``. At block=1 the full 4·n_bins chain of Alg. 1 runs (lazily,
     in chunks of ``chunk`` salts); at block>1 the budget is the ``chunk``
-    pre-hashed candidates in ``cand0``. Exhausting the budget falls back
-    to the least-loaded snapshot bin (Alg. 1's fallback).
+    pre-hashed candidates in ``cand0`` (one array per salt, shaped like
+    ``kblk``). Exhausting the budget falls back to the least-loaded
+    snapshot bin (Alg. 1's fallback).
     """
     max_probes = 4 * n_bins
-    salts0 = probe_salts(chunk)
-    assign = snapshot_resolve(load, cap, cand0, salts0,
-                              jnp.full((block,), -1, jnp.int32), max_probes)
+    assign = snapshot_resolve(table, load, cap, cand0, range(1, chunk + 1),
+                              jnp.full(kblk.shape, -1, jnp.int32),
+                              max_probes)
 
     if block == 1:
         # exactness: continue the salted chain to the oracle ceiling
         def cond(c):
             salt0, assign = c
-            return (salt0 <= max_probes) & jnp.any(assign < 0)
+            return (salt0 <= max_probes) & (jnp.min(assign) < 0)
 
         def probe_chunk(c):
             salt0, assign = c
-            salts = salt0 + jax.lax.iota(jnp.uint32, chunk)
-            cand = hash_to_bins(kblk[:, None], salts[None, :], n_bins)
-            return salt0 + chunk, snapshot_resolve(load, cap, cand, salts,
-                                                   assign, max_probes)
+            salts = [salt0 + jnp.uint32(j) for j in range(chunk)]
+            cands = [hash_to_bins(kblk, s, n_bins) for s in salts]
+            return salt0 + chunk, snapshot_resolve(table, load, cap, cands,
+                                                   salts, assign, max_probes)
 
         _, assign = jax.lax.while_loop(
             cond, probe_chunk, (jnp.uint32(1 + chunk), assign))
 
     # probe budget exhausted: least-loaded snapshot bin (Alg. 1)
-    return jnp.where(assign < 0, jnp.argmin(load).astype(jnp.int32), assign)
+    return jnp.where(assign < 0, table.argmin(load), assign)
 
 
 # ---------------------------------------------------------------------------

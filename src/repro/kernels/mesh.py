@@ -24,13 +24,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.hashing import hash_to_bins
 from repro.kernels.ref import (MultiSourcePorcState, _porc_multisource_tail,
-                               _snapshot_block, block_spans,
-                               multisource_state_init)
+                               _snapshot_block, block_spans, count_sum,
+                               multisource_state_init, salt_columns,
+                               view_cap)
 
 SOURCES_AXIS = "sources"
 
@@ -82,12 +82,12 @@ def _mesh_scan(mesh, n_bins: int, n_sources: int, sync_every: int,
             # source can verify its cap against base + its own delta
             # without any cross-host traffic (see ref.py for why the
             # per-source invariant telescopes to the global envelope)
-            mass = base.sum() + delta.sum(1)
-            cap = (1.0 + eps) * (mass + block / S) / n_bins
+            mass = count_sum(base) + count_sum(delta, 1)
+            cap = view_cap(eps, n_bins, mass, block / S)
             views = base[None, :] + delta
             assign = jax.vmap(
                 lambda view, c, kk, cb: _snapshot_block(
-                    view, c, kk, cb, n_bins, block, chunk))(
+                    view, c, kk, salt_columns(cb), n_bins, block, chunk))(
                 views, cap, kblk, cblk)
             delta = jax.vmap(lambda d, a: d.at[a].add(1.0))(delta, assign)
             # piggyback merge = all-reduce of the lane deltas. The psum
@@ -109,11 +109,10 @@ def _mesh_scan(mesh, n_bins: int, n_sources: int, sync_every: int,
              cand0.transpose(1, 0, 2, 3)))
         return base, delta, assign.transpose(1, 0, 2)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(SOURCES_AXIS, None), P(), P(SOURCES_AXIS, None, None)),
-        out_specs=(P(), P(SOURCES_AXIS, None), P(SOURCES_AXIS, None, None)),
-        check_rep=False))
+        out_specs=(P(), P(SOURCES_AXIS, None), P(SOURCES_AXIS, None, None))))
 
 
 def mesh_porc_multisource(keys: jnp.ndarray, n_bins: int, mesh, *,

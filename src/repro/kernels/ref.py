@@ -28,6 +28,7 @@ from repro.core.hashing import hash_to_bins
 from .blocks import (  # noqa: F401  (re-exports)
     HHPolicy,
     SKETCH_SALT0 as _SKETCH_SALT0,
+    count_sum,
     hh_budgets as _hh_budgets,
     hh_chunk as _hh_chunk,
     hh_sketch_init,
@@ -35,11 +36,11 @@ from .blocks import (  # noqa: F401  (re-exports)
     hh_sketch_update,
     neutral_hh_policy,
     probe_salts,
+    salt_columns,
     sketch_cols as _sketch_cols,
     snapshot_block as _snapshot_block,
     snapshot_block_hh as _snapshot_block_hh,
     snapshot_cap,
-    snapshot_resolve as _snapshot_resolve,
     view_cap,
 )
 
@@ -220,7 +221,8 @@ def ref_porc_snapshot(keys: jnp.ndarray, n_bins: int, *, block: int = 128,
     def blk(load, xs):
         b, kblk, cblk = xs
         cap = snapshot_cap(eps, n_bins, m0, b, block)
-        assign = _snapshot_block(load, cap, kblk, cblk, n_bins, block, chunk)
+        assign = _snapshot_block(load, cap, kblk, salt_columns(cblk), n_bins,
+                                 block, chunk)
         return load.at[assign].add(1.0), assign
 
     load, assign = jax.lax.scan(blk, load,
@@ -415,7 +417,7 @@ def _porc_multisource_scan(keys: jnp.ndarray, n_bins: int, n_sources: int,
             xs_extra = ()
         route_block = jax.vmap(
             lambda view, cap, kblk, cblk: _snapshot_block(
-                view, cap, kblk, cblk, n_bins, block, chunk),
+                view, cap, kblk, salt_columns(cblk), n_bins, block, chunk),
             in_axes=(0, 0, 0, 0))
     else:        # "strict": in-block contention resolved rank by rank
         assert policy is None, "HHPolicy requires the snapshot engine"
@@ -441,7 +443,7 @@ def _porc_multisource_scan(keys: jnp.ndarray, n_bins: int, n_sources: int,
         # (at S=1 this reduces bit-exactly to ``ref_porc_snapshot``'s
         # capacity); a full +block per source would hand the S sources
         # S·(1+eps)·block/n of joint slack on a shared hot bin.
-        mass = base.sum() + delta.sum(1)                  # [S] local view
+        mass = count_sum(base) + count_sum(delta, 1)      # [S] local view
         cap = view_cap(eps, n_bins, mass, block / S)
         views = base[None, :] + delta                     # [S, n_bins]
         if policy is None:
@@ -500,12 +502,12 @@ def _porc_multisource_tail(keys_pad: jnp.ndarray, n_bins: int, n_sources: int,
     chunk_eff = chunk if policy is None else _hh_chunk(policy, chunk, n_bins)
     cand0 = hash_to_bins(keys_pad[:, None, None], probe_salts(chunk_eff),
                          n_bins)
-    mass = base0.sum() + delta0.sum(1)
+    mass = count_sum(base0) + count_sum(delta0, 1)
     cap = view_cap(eps, n_bins, mass, 1.0 / S)
     if policy is None:
         assign = jax.vmap(
             lambda view, kblk, cblk, c: _snapshot_block(
-                view, c, kblk, cblk, n_bins, 1, chunk))(
+                view, c, kblk, salt_columns(cblk), n_bins, 1, chunk))(
             base0[None, :] + delta0, keys_pad[:, None], cand0, cap)[:, 0]
         skb, skd = skb0, skd0
     else:

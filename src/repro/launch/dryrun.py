@@ -31,7 +31,7 @@ from repro.models import model_zoo as zoo  # noqa: E402
 
 from . import sharding as shd  # noqa: E402
 from . import steps  # noqa: E402
-from .mesh import enter_mesh, make_production_mesh  # noqa: E402
+from .mesh import make_production_mesh  # noqa: E402
 
 _LINE_RE = re.compile(
     r"=\s+(?P<rtype>\([^)]*\)|\S+)\s+"
@@ -78,7 +78,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     steps.install_act_rules(mesh)
     t0 = time.time()
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         ins = zoo.input_specs(cfg, shape)
         if shape.kind == "train":
             jit_for, p_sh, o_sh = steps.jit_train_step(cfg, mesh)

@@ -10,6 +10,7 @@ TPU v5e constants used by the roofline (benchmarks/roofline.py):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 PEAK_FLOPS = 197e12          # bf16 per chip
 HBM_BW = 819e9               # bytes/s per chip
@@ -19,12 +20,12 @@ ICI_BW = 50e9                # bytes/s per link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over the real local device(s) for integration tests."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_source_mesh(n_hosts: int | None = None):
@@ -34,18 +35,16 @@ def make_source_mesh(n_hosts: int | None = None):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` that is the
     N simulated hosts the multihost bench and CI job use."""
     n = n_hosts or len(jax.devices())
-    return jax.make_mesh((n,), ("sources",))
+    return _auto_mesh((n,), ("sources",))
 
 
-def enter_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    jax >= 0.6 exposes ``jax.set_mesh``; on the 0.4.x line the ``Mesh``
-    object itself is the context manager with the same effect.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the programs here
+    place data with ``NamedSharding``/``with_sharding_constraint`` and
+    let the partitioner propagate the rest (``make_mesh`` would
+    otherwise default to ``Explicit`` axes, under which a sharding
+    constraint is an assertion)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

@@ -17,7 +17,7 @@ from repro.models import model_zoo as zoo
 from repro.serve import CGRequestRouter, ServingEngine
 
 from . import steps
-from .mesh import enter_mesh, make_smoke_mesh
+from .mesh import make_smoke_mesh
 
 
 def build_replica(cfg, params, decode_steps: int, slow: float = 0.0,
@@ -61,7 +61,7 @@ def main():
     cfg = configs.get_smoke_config(args.arch)
     mesh = make_smoke_mesh()
     steps.install_act_rules(mesh)
-    mesh_ctx = enter_mesh(mesh)
+    mesh_ctx = jax.set_mesh(mesh)
     mesh_ctx.__enter__()
     params = zoo.init_params(cfg, jax.random.PRNGKey(0))
 

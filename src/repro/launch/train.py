@@ -25,7 +25,7 @@ from repro.models import model_zoo as zoo
 from repro.runtime import DelegationBalancer, FTConfig, FaultTolerantRunner
 
 from . import steps
-from .mesh import enter_mesh, make_smoke_mesh
+from .mesh import make_smoke_mesh
 
 
 def train(arch: str, n_steps: int = 20, batch: int = 8, seq: int = 128,
@@ -36,7 +36,7 @@ def train(arch: str, n_steps: int = 20, batch: int = 8, seq: int = 128,
     cfg = configs.get_smoke_config(arch) if smoke else configs.get_config(arch)
     mesh = make_smoke_mesh()
     steps.install_act_rules(mesh)
-    mesh_ctx = enter_mesh(mesh)
+    mesh_ctx = jax.set_mesh(mesh)
     mesh_ctx.__enter__()
     opt_cfg = optim.AdamWConfig(lr_peak=lr, warmup_steps=max(2, n_steps // 10),
                                 total_steps=n_steps)

@@ -413,7 +413,7 @@ class CGRequestRouter:
             jnp.asarray(keys), self.n_virtual, self.n_sources,
             sync_every=self.sync_every, block=self.block_size,
             eps=self.eps, state=self._state, policy=self._policy,
-            engine=resolve_engine(self.engine))
+            engine=resolve_engine(self.engine, self._policy))
         self._routed += len(keys)
         return assign_vw
 

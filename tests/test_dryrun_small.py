@@ -11,7 +11,7 @@ import pytest
 
 from repro import configs, optim
 from repro.launch import steps
-from repro.launch.mesh import enter_mesh, make_smoke_mesh
+from repro.launch.mesh import make_smoke_mesh
 from repro.models import model_zoo as zoo
 
 ARCHS = ["gemma3-1b", "qwen3-moe-235b-a22b", "mamba2-130m", "whisper-small"]
@@ -34,7 +34,7 @@ def _batch_specs(cfg, B=2, S=32):
 def test_train_step_compiles(arch):
     cfg = configs.get_smoke_config(arch)
     mesh = make_smoke_mesh()
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         jit_for, p_sh, o_sh = steps.jit_train_step(cfg, mesh)
         pspecs = zoo.param_specs(cfg)
         ospecs = jax.eval_shape(optim.init, pspecs)
@@ -47,7 +47,7 @@ def test_train_step_compiles(arch):
 def test_serve_step_compiles(arch):
     cfg = configs.get_smoke_config(arch)
     mesh = make_smoke_mesh()
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         jit_for, p_sh = steps.jit_serve_step(cfg, mesh)
         pspecs = zoo.param_specs(cfg)
         cache = zoo.cache_spec(cfg, 2, 32)
@@ -59,7 +59,7 @@ def test_serve_step_compiles(arch):
 def test_prefill_step_compiles():
     cfg = configs.get_smoke_config("internlm2-20b")
     mesh = make_smoke_mesh()
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         jit_for, _ = steps.jit_prefill_step(cfg, mesh)
         pspecs = zoo.param_specs(cfg)
         batch = _batch_specs(cfg)

@@ -217,3 +217,45 @@ def test_router_porc_single_matches_stream():
     outs = [r.route(k) for k in [1, 1, 1, 1, 2, 3, 1, 1]]
     assert all(0 <= o < 4 for o in outs)
     assert r.vw_load.sum() == 8
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    checkout = compile_cache.CHECKOUT_CACHE.parent
+    assert got == str(checkout / ".jax_cache")
+    assert (checkout / "src" / "repro").is_dir()
+    ignored = (checkout / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
+
+
+def test_compile_cache_follows_env(tmp_path):
+    """A directory placed from outside wins, and compiles land there."""
+    import subprocess
+    import sys
+    from repro.launch import compile_cache
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "jax.jit(lambda x: jnp.sin(x) * 3.0)(1.0).block_until_ready()\n")
+    src = str(compile_cache.CHECKOUT_CACHE.parent / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in (src, os.environ.get("PYTHONPATH")) if p))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split()[-1] == str(tmp_path)
+    assert any(tmp_path.iterdir())
