@@ -67,6 +67,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import trace
+
 from . import controller, delegation
 from .hashing import hash_to_bins
 
@@ -387,8 +389,10 @@ def run(cfg: CGConfig, keys: jnp.ndarray, capacities: jnp.ndarray,
         vw_load, sketch, vw = _route_slot(cfg, state.vw_load,
                                           state.t_offset, state.sg_ptr,
                                           state.sketch, slot_keys)
-        workers = state.vw_owner[vw]                       # [slot_len]
-        arrivals = jnp.zeros(cfg.n_workers, jnp.float32).at[workers].add(1.0)
+        with trace.scope(trace.BIND):
+            workers = state.vw_owner[vw]                   # [slot_len]
+            arrivals = jnp.zeros(cfg.n_workers,
+                                 jnp.float32).at[workers].add(1.0)
 
         service = c * cfg.slot_len                          # msgs drainable
         q0 = state.queues

@@ -47,6 +47,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import trace
+
 
 class ControllerConfig(NamedTuple):
     n_workers: int
@@ -128,6 +130,14 @@ def controller_step(cfg: ControllerConfig, state: ControllerState,
     ``busy``/``idle``/``budget`` straight into
     ``delegation.rebalance_step`` (both shapes are accepted).
     """
+    with trace.scope(trace.CONTROLLER):
+        return _controller_step(cfg, state, pressure, depths, unit,
+                                enter_busy, exit_busy, enter_idle,
+                                exit_idle, unit_bytes)
+
+
+def _controller_step(cfg, state, pressure, depths, unit, enter_busy,
+                     exit_busy, enter_idle, exit_idle, unit_bytes):
     pressure = jnp.asarray(pressure, jnp.float32)
     depths = jnp.asarray(depths, jnp.float32)
     raw_busy = pressure > enter_busy

@@ -70,6 +70,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import trace
+
 NOT_QUEUED = jnp.iinfo(jnp.int32).max     # sorts after every real slot
 
 
@@ -362,6 +364,13 @@ def rebalance_step(cfg: DelegationConfig, state: DelegationState, pressure,
 
     Returns (new DelegationState, n_moved i32).
     """
+    with trace.scope(trace.DELEGATION):
+        return _rebalance_step(cfg, state, pressure, busy, idle,
+                               vw_arrivals, capacities, budget, vw_bytes)
+
+
+def _rebalance_step(cfg, state, pressure, busy, idle, vw_arrivals,
+                    capacities, budget, vw_bytes):
     pressure = jnp.asarray(pressure, jnp.float32)
     rate = cfg.rate_decay * state.vw_rate + jnp.asarray(vw_arrivals,
                                                        jnp.float32)

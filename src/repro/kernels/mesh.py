@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import trace
 from repro.core.hashing import hash_to_bins
 from repro.kernels.ref import (MultiSourcePorcState, _porc_multisource_tail,
                                _snapshot_block, block_spans, count_sum,
@@ -95,9 +96,10 @@ def _mesh_scan(mesh, n_bins: int, n_sources: int, sync_every: int,
             # blocks); counts are integer-valued f32, so the different
             # reduction order vs delta.sum(0) is still bit-exact.
             sync = ((ticks0 + b + 1) % sync_every) == 0
-            merged = jax.lax.psum(
-                jnp.where(sync, delta.sum(0), jnp.zeros((n_bins,))),
-                SOURCES_AXIS)
+            with trace.scope(trace.MERGE):
+                merged = jax.lax.psum(
+                    jnp.where(sync, delta.sum(0), jnp.zeros((n_bins,))),
+                    SOURCES_AXIS)
             base = jnp.where(sync, base + merged, base)
             delta = jnp.where(sync, jnp.zeros_like(delta), delta)
             return (base, delta), assign

@@ -165,6 +165,7 @@ def porc_snapshot(keys: jnp.ndarray, n_bins: int, *, block: int = 128,
         scratch_shapes=[pltpu.VMEM((n_bins, 1), jnp.float32)],
         compiler_params=_SEQUENTIAL,
         interpret=resolve_interpret(interpret),
+        name="porc_snapshot",
     )(m0_arr, load0_arr.reshape(n_bins, 1), keys.reshape(n_blocks, 1, block))
     return assign.reshape(M), load.reshape(n_bins)
 
@@ -353,7 +354,7 @@ def porc_multisource_scan(keys: jnp.ndarray, n_bins: int, n_sources: int,
         kernel, grid=(nb,),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=scratch, compiler_params=_SEQUENTIAL,
-        interpret=interpret,
+        interpret=interpret, name="porc_multisource_scan",
     )(*operands)
     if policy is None:
         assign, base, delta = outs

@@ -23,6 +23,7 @@ from typing import NamedTuple
 
 import numpy as np
 
+from repro import trace
 from repro.core import controller, delegation
 from repro.core.hashing import hash_to_bins
 from repro.kernels.ref import (multisource_merge, multisource_state_init,
@@ -202,6 +203,10 @@ class CGRequestRouter:
         else:
             self._controller = None
         self._rebalance_mark = 0    # routed count at the last rebalance
+        # batches dispatched and finalized: a batch's sequence number in
+        # the trace of both halves
+        self._dispatched = 0
+        self._finalized = 0
 
     @property
     def controller_active(self) -> bool:
@@ -222,7 +227,8 @@ class CGRequestRouter:
     def vw_owner(self) -> np.ndarray:
         """Virtual-replica → replica map, as a fresh NumPy download (the
         authoritative copy is device-resident). Assign to replace it."""
-        return np.asarray(self._dstate.vw_owner)
+        with trace.span(trace.DEVICE_WAIT):
+            return np.asarray(self._dstate.vw_owner)
 
     @vw_owner.setter
     def vw_owner(self, value) -> None:
@@ -275,10 +281,11 @@ class CGRequestRouter:
         ``(n_moved, bytes_moved)``."""
         caps = (np.ones(self.n_replicas, np.float64) if capacities is None
                 else np.asarray(capacities, np.float64))
+        with trace.span(trace.DEVICE_WAIT):
+            owner = np.asarray(self._dstate.vw_owner)
+            rate = np.asarray(self._dstate.vw_rate)
         new_owner, n_moved, nbytes = delegation.evacuate(
-            np.asarray(self._dstate.vw_owner),
-            np.asarray(self._dstate.vw_rate), replica, caps,
-            vw_bytes=self._vw_bytes)
+            owner, rate, replica, caps, vw_bytes=self._vw_bytes)
         if n_moved:
             self._dstate = self._dstate._replace(
                 vw_owner=jnp.asarray(new_owner, jnp.int32),
@@ -349,7 +356,9 @@ class CGRequestRouter:
         if (1.0 + self.eps) * self._routed / self.n_virtual + stale < 2 ** 23:
             return
         old_routed = self._routed
-        shift = float(jnp.min(self._state.base + self._state.delta.sum(0)))
+        with trace.span(trace.DEVICE_WAIT):
+            shift = float(jnp.min(self._state.base
+                                  + self._state.delta.sum(0)))
         self._routed -= int(shift * self.n_virtual)
         self._rebalance_mark -= int(shift * self.n_virtual)
         self._state = self._state._replace(
@@ -406,29 +415,45 @@ class CGRequestRouter:
         assignment array without forcing a host sync — the async submit
         path overlaps this with serving. ``finalize_batch`` turns the
         handle into replica ids."""
-        keys = np.asarray(keys, np.int32)
-        self._maybe_rebase()
+        self._dispatched += 1
+        with trace.span(trace.DISPATCH, batch=self._dispatched):
+            keys = np.asarray(keys, np.int32)
+            self._maybe_rebase()
+            assign_vw, self._state = self._route_keys(jnp.asarray(keys))
+            self._routed += len(keys)
+            return assign_vw
+
+    def _route_keys(self, keys):
+        """Launch PoRC over ``keys`` from the current routing state:
+        ``(assign_vw, new state)``. The mesh router routes on its mesh."""
         from repro.kernels import resolve_engine
-        assign_vw, self._state = ref_porc_multisource(
-            jnp.asarray(keys), self.n_virtual, self.n_sources,
+        return ref_porc_multisource(
+            keys, self.n_virtual, self.n_sources,
             sync_every=self.sync_every, block=self.block_size,
             eps=self.eps, state=self._state, policy=self._policy,
             engine=resolve_engine(self.engine, self._policy))
-        self._routed += len(keys)
-        return assign_vw
 
     def finalize_batch(self, assign_vw) -> np.ndarray:
         """Admission half: bind a dispatched VW assignment to replicas
         through the (possibly versioned) owner view and settle the
         per-VW state-byte accrual. This is where the host blocks on the
-        device result."""
-        if self._vw_bytes is not None and self.state_bytes_per_request > 0:
-            # keyed session state grows where the requests land
-            self._vw_bytes += self.state_bytes_per_request * np.bincount(
-                np.asarray(assign_vw).ravel(), minlength=self.n_virtual)
-        # owner gather on device — the owner map never leaves it
-        return np.asarray(jnp.take(self._owner_view(),
-                                   jnp.asarray(assign_vw)))
+        device result. Handles are finalized in the order they were
+        dispatched (the engine admits parked dispatches in order), so
+        the n-th finalize traces as the same ``batch`` as the n-th
+        dispatch."""
+        self._finalized += 1
+        with trace.span(trace.FINALIZE, batch=self._finalized):
+            if (self._vw_bytes is not None
+                    and self.state_bytes_per_request > 0):
+                # keyed session state grows where the requests land
+                with trace.span(trace.DEVICE_WAIT):
+                    vws = np.asarray(assign_vw).ravel()
+                self._vw_bytes += self.state_bytes_per_request * np.bincount(
+                    vws, minlength=self.n_virtual)
+            # owner gather on device — the owner map never leaves it
+            owners = jnp.take(self._owner_view(), jnp.asarray(assign_vw))
+            with trace.span(trace.DEVICE_WAIT):
+                return np.asarray(owners)
 
     def route_batch(self, keys: np.ndarray) -> np.ndarray:
         """Sharded block-parallel PoRC over virtual replicas (the
@@ -461,6 +486,10 @@ class CGRequestRouter:
         defaults to ``pressure · max_queue``), clamped to
         ``[min_moves, max_moves_per_rebalance]``.
         """
+        with trace.span(trace.REBALANCE):
+            return self._rebalance(busy, idle, pressure, capacities, depths)
+
+    def _rebalance(self, busy, idle, pressure, capacities, depths) -> int:
         n = self.n_replicas
         budget = None
         if self._controller is not None and pressure is None:
@@ -487,7 +516,8 @@ class CGRequestRouter:
                   else max(float(self._vw_bytes.mean()), 1.0))
             busy_j, idle_j, budget_j = self._controller.step(
                 p, d, unit, unit_bytes=ub)
-            busy_mask, idle_mask = np.asarray(busy_j), np.asarray(idle_j)
+            with trace.span(trace.DEVICE_WAIT):
+                busy_mask, idle_mask = np.asarray(busy_j), np.asarray(idle_j)
             budget = budget_j if self.adaptive_moves else None
             if (not busy_mask.any() and not self._queued_busy) or (
                     not idle_mask.any() and not self._queued_idle):
@@ -521,12 +551,15 @@ class CGRequestRouter:
             jnp.asarray(busy_mask), jnp.asarray(idle_mask),
             load - self._rated_load, caps, budget, vb)
         self._rated_load = load
-        if int(moved):
-            self._note_owner_update()
         q = self._dstate.queues
-        self._queued_busy = bool(jnp.any(q.busy_since != delegation.NOT_QUEUED))
-        self._queued_idle = bool(jnp.any(q.idle_since != delegation.NOT_QUEUED))
-        moved = int(moved)
+        with trace.span(trace.DEVICE_WAIT):
+            moved = int(moved)
+            self._queued_busy = bool(jnp.any(
+                q.busy_since != delegation.NOT_QUEUED))
+            self._queued_idle = bool(jnp.any(
+                q.idle_since != delegation.NOT_QUEUED))
+        if moved:
+            self._note_owner_update()
         self.moves += moved
         return moved
 
@@ -641,6 +674,7 @@ class ServingEngine:
         self._beating = np.ones(n, bool)
         self._last_beat = np.zeros(n, np.int64)
         self._readmit = np.ones(n, np.float64)
+        trace.install_gc_spans()
 
     # -- request intake ---------------------------------------------------
     def submit(self, key: int, payload) -> None:
@@ -674,16 +708,17 @@ class ServingEngine:
         if not self._pending:
             return
         pending, self._pending = self._pending, []
-        for handle, keys, payloads, t0, tick in pending:
-            assign = self.router.finalize_batch(handle)
-            for a, k, p in zip(assign, keys, payloads):
-                req = Request(t0, tick, int(k), p, enq=self.step_idx)
-                rep = self.replicas[int(a)]
-                if rep.alive or not self._dead[int(a)]:
-                    rep.queue.append(req)
-                else:
-                    self._schedule_retry(req)
-                    self.retried += 1
+        with trace.span(trace.ADMIT):
+            for handle, keys, payloads, t0, tick in pending:
+                assign = self.router.finalize_batch(handle)
+                for a, k, p in zip(assign, keys, payloads):
+                    req = Request(t0, tick, int(k), p, enq=self.step_idx)
+                    rep = self.replicas[int(a)]
+                    if rep.alive or not self._dead[int(a)]:
+                        rep.queue.append(req)
+                    else:
+                        self._schedule_retry(req)
+                        self.retried += 1
 
     @property
     def in_flight(self) -> int:
@@ -807,15 +842,41 @@ class ServingEngine:
         max_batch requests, then delegation signals fire and the router
         re-pairs busy↔idle in severity order (most-overloaded with
         most-idle, §V-B) using queue occupancy as the pressure signal."""
-        self.step_idx += 1
-        if self.chaos is not None:
-            for ev in self.chaos.pop_due(self.step_idx):
-                self.apply_chaos(ev)
-        self._check_liveness()
-        self._admit_pending()
-        self._drain_retries()
+        with trace.span(trace.STEP):
+            self.step_idx += 1
+            if self.chaos is not None:
+                for ev in self.chaos.pop_due(self.step_idx):
+                    self.apply_chaos(ev)
+            self._check_liveness()
+            self._admit_pending()
+            self._drain_retries()
+            with trace.span(trace.SERVE_REPLICAS):
+                served, occupancy = self._serve_replicas()
+            # re-admission ramp: recovered replicas earn their share back
+            below = self._readmit < 1.0
+            if below.any() and self.readmit_ramp_steps > 0:
+                alive = np.asarray([r.alive for r in self.replicas])
+                self._readmit[below & alive] = np.minimum(
+                    1.0, self._readmit[below & alive]
+                    + 1.0 / self.readmit_ramp_steps)
+            busy = [i for i, r in enumerate(self.replicas) if r.busy_signal]
+            idle = [i for i, r in enumerate(self.replicas) if r.idle_signal]
+            # with the adaptive controller on, every tick must reach the
+            # router so the hysteresis latches and depth EWMA stay current
+            if busy or idle or self.router.controller_active:
+                before = (self.router.vw_owner if self.migrator is not None
+                          else None)
+                self.router.rebalance(
+                    busy, idle, pressure=occupancy,
+                    capacities=self._effective_capacities(),
+                    depths=np.asarray(self.queue_depths(), np.float32))
+                self._migrate_owner_changes(before)
+            return served
+
+    def _serve_replicas(self) -> tuple[int, np.ndarray]:
+        """Each live replica serves up to its batch and raises its
+        busy/idle signal. Returns (requests served, queue occupancy)."""
         served = 0
-        now = time.monotonic()
         occupancy = np.zeros(len(self.replicas), np.float32)
         for i, (rep, fn) in enumerate(zip(self.replicas, self.fns)):
             if not rep.alive:
@@ -891,26 +952,7 @@ class ServingEngine:
             occupancy[i] = occ
             rep.busy_signal = occ > self.router.queue_hi
             rep.idle_signal = occ < self.router.queue_lo
-        # re-admission ramp: recovered replicas earn their share back
-        below = self._readmit < 1.0
-        if below.any() and self.readmit_ramp_steps > 0:
-            alive = np.asarray([r.alive for r in self.replicas])
-            self._readmit[below & alive] = np.minimum(
-                1.0, self._readmit[below & alive]
-                + 1.0 / self.readmit_ramp_steps)
-        busy = [i for i, r in enumerate(self.replicas) if r.busy_signal]
-        idle = [i for i, r in enumerate(self.replicas) if r.idle_signal]
-        # with the adaptive controller on, every tick must reach the
-        # router so the hysteresis latches and depth EWMA stay current
-        if busy or idle or self.router.controller_active:
-            before = (self.router.vw_owner if self.migrator is not None
-                      else None)
-            self.router.rebalance(
-                busy, idle, pressure=occupancy,
-                capacities=self._effective_capacities(),
-                depths=np.asarray(self.queue_depths(), np.float32))
-            self._migrate_owner_changes(before)
-        return served
+        return served, occupancy
 
     def queue_depths(self) -> list[int]:
         return [len(r.queue) for r in self.replicas]

@@ -33,9 +33,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-import jax.numpy as jnp
-import numpy as np
-
 from repro.core import delegation
 from repro.kernels.mesh import (SOURCES_AXIS, mesh_porc_multisource,
                                 shard_multisource_state)
@@ -100,17 +97,13 @@ class MeshCGRequestRouter(CGRequestRouter):
             self._commits_behind = 0
 
     # -- sharded submit path ----------------------------------------------
-    def dispatch_batch(self, keys: np.ndarray):
-        """Routing half of the submit path, on the mesh: the batch
-        splits round-robin across the source lanes, each host routes
-        its lanes against base + its own deltas under ``shard_map``,
-        and the delta-merge is a psum over the ``sources`` axis. Same
-        handle contract as the base class."""
-        keys = np.asarray(keys, np.int32)
-        self._maybe_rebase()
-        assign_vw, self._state = mesh_porc_multisource(
-            jnp.asarray(keys), self.n_virtual, self.mesh,
-            n_sources=self.n_sources, sync_every=self.sync_every,
-            block=self.block_size, eps=self.eps, state=self._state)
-        self._routed += len(keys)
-        return assign_vw
+    def _route_keys(self, keys):
+        """The routing launch of ``dispatch_batch``, on the mesh: the
+        batch splits round-robin across the source lanes, each host
+        routes its lanes against base + its own deltas under
+        ``shard_map``, and the delta-merge is a psum over the
+        ``sources`` axis."""
+        return mesh_porc_multisource(
+            keys, self.n_virtual, self.mesh, n_sources=self.n_sources,
+            sync_every=self.sync_every, block=self.block_size, eps=self.eps,
+            state=self._state)

@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time, and every
 test worker imports every test file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -123,3 +125,7 @@ def test_cg_run_default_engine_compiles_to_the_kernel(one_chip, chip_compile,
         cfg, _shape((20 * cfg.slot_len,), jnp.int32, one_chip),
         _shape((cfg.n_workers,), jnp.float32, one_chip)).compile()
     assert _has_kernel(compiled)
+    # the chip trace names the kernel's events after this instruction
+    assert re.search(r'^\s*%porc_multisource_scan[.0-9]* = '
+                     r'.*custom_call_target="tpu_custom_call"',
+                     compiled.as_text(), re.M)
