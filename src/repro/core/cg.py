@@ -353,6 +353,28 @@ def _route_slot(cfg: CGConfig, vw_load, t_offset, sg_ptr, sketch, keys):
     return vw_load, sketch, vw
 
 
+def _bind(vw_owner, vw, n_workers: int):
+    """Bind a slot's messages to workers: ``(vw_owner[vw], arrivals)``.
+
+    ``arrivals`` is ``bincount(workers, minlength=n_workers)`` as float32.
+    Both are compare-against-iota reductions, as the kernels' ``[n_bins,
+    1]`` tables are read (``porc_snapshot._take``/``_count``): the TPU
+    lowers a gather or scatter-add one index at a time, and a slot has
+    ``slot_len`` of each. Every sum is an integer count below 2^24, so it
+    is exact in any order and the result is the gather's and the
+    scatter-add's bit for bit.
+    """
+    m = vw.shape[0]
+    hits = vw[None, :] == jax.lax.broadcasted_iota(
+        jnp.int32, (vw_owner.shape[0], m), 0)                   # [V, m]
+    workers = jnp.sum(jnp.where(hits, vw_owner[:, None], 0),
+                      axis=0).astype(vw_owner.dtype)            # [m]
+    mine = workers[None, :] == jax.lax.broadcasted_iota(
+        jnp.int32, (n_workers, m), 0)                           # [n, m]
+    arrivals = jnp.sum(mine, axis=1).astype(jnp.float32)        # [n]
+    return workers, arrivals
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def run(cfg: CGConfig, keys: jnp.ndarray, capacities: jnp.ndarray,
         state: CGState | None = None) -> CGResult:
@@ -390,9 +412,7 @@ def run(cfg: CGConfig, keys: jnp.ndarray, capacities: jnp.ndarray,
                                           state.t_offset, state.sg_ptr,
                                           state.sketch, slot_keys)
         with trace.scope(trace.BIND):
-            workers = state.vw_owner[vw]                   # [slot_len]
-            arrivals = jnp.zeros(cfg.n_workers,
-                                 jnp.float32).at[workers].add(1.0)
+            workers, arrivals = _bind(state.vw_owner, vw, cfg.n_workers)
 
         service = c * cfg.slot_len                          # msgs drainable
         q0 = state.queues

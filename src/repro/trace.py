@@ -28,7 +28,7 @@ Spans nest as the serving layers do::
         cg.device_wait
     cg.gc                             a full collection, anywhere
 
-Scopes on the device: ``cg.bind`` (owner gather and arrival count of a
+Scopes on the device: ``cg.bind`` (owner lookup and arrival count of a
 slot), ``cg.controller``, ``cg.delegation``, ``cg.merge`` (the psum of
 the source lanes across a mesh). ``docs/tracing.md`` says how to capture
 a trace and what each name covers.

@@ -312,3 +312,51 @@ def test_capacity_weighted_tracks_time_varying_capacity(keys):
                    keys, jnp.asarray(caps, jnp.float32))
     settled_u = np.mean(np.asarray(res_u.imbalance)[-3:])
     assert settled < settled_u, (settled, settled_u)
+
+
+# ---------------------------------------------------------------------------
+# the bind step: a slot's workers and per-worker arrivals
+# ---------------------------------------------------------------------------
+
+def _moved_owner(n, alpha):
+    """An owner map after real paired moves: a short heterogeneous run."""
+    cfg = cg.CGConfig(n_workers=n, alpha=alpha, eps=0.01, slot_len=1000)
+    keys = streams.sample_zipf_stream(jax.random.PRNGKey(1), 20_000, 500,
+                                      1.1)
+    res = cg.run(cfg, keys, _caps(n, 2, 4.0))
+    assert int(res.moves) > 0
+    owner = np.asarray(res.state.vw_owner)
+    assert not np.array_equal(owner, np.tile(np.arange(n), alpha))
+    return owner
+
+
+@pytest.mark.parametrize("V, n, m, case", [
+    (240, 24, 10_000, "skewed"),       # the Storm deployment's slot
+    (1000, 100, 10_000, "skewed"),     # Fig 11's largest fleets
+    (6, 3, 64, "skewed"),
+    (240, 24, 10_000, "one_vw"),       # every message on one VW
+    (240, 24, 10_000, "idle_worker"),  # a worker owning no VW
+    (40, 8, 1000, "moved"),            # an owner map after moves
+])
+def test_bind_matches_gather_and_bincount(V, n, m, case):
+    rs = np.random.RandomState(V + m)
+    if case == "moved":
+        owner = _moved_owner(n, V // n)
+    elif case == "idle_worker":
+        owner = np.arange(V) % (n - 1)
+    else:
+        owner = rs.permutation(np.tile(np.arange(n), V // n))
+    if case == "one_vw":
+        vw = np.full(m, V // 3)
+    else:
+        p = 1.0 / np.arange(1, V + 1)
+        vw = rs.choice(V, size=m, p=p / p.sum())
+    owner = owner.astype(np.int32)
+    vw = vw.astype(np.int32)
+    workers, arrivals = jax.jit(cg._bind, static_argnums=2)(
+        jnp.asarray(owner), jnp.asarray(vw), n)
+    assert workers.dtype == jnp.int32 and arrivals.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(workers), owner[vw])
+    np.testing.assert_array_equal(
+        np.asarray(arrivals),
+        np.bincount(owner[vw], minlength=n).astype(np.float32))

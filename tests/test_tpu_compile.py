@@ -129,3 +129,21 @@ def test_cg_run_default_engine_compiles_to_the_kernel(one_chip, chip_compile,
     assert re.search(r'^\s*%porc_multisource_scan[.0-9]* = '
                      r'.*custom_call_target="tpu_custom_call"',
                      compiled.as_text(), re.M)
+
+
+def test_cg_run_binds_without_gather_or_scatter(one_chip, chip_compile,
+                                                 monkeypatch):
+    """The slot loop's bind step (the ``cg.bind`` scope) compiles to
+    compare-and-reduce fusions: the TPU runs a gather or a scatter one
+    index at a time, and a slot would have 10,000 of each."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    cfg = cg.CGConfig(n_workers=24, alpha=10, eps=EPS, slot_len=10_000,
+                      block_size=BLOCK, n_sources=S)
+    text = cg.run.lower(
+        cfg, _shape((2 * cfg.slot_len,), jnp.int32, one_chip),
+        _shape((cfg.n_workers,), jnp.float32, one_chip)).compile().as_text()
+    bind = [line for line in text.splitlines()
+            if re.search(r'op_name="[^"]*cg\.bind[/"]', line)]
+    assert bind, "no instruction carries the cg.bind scope"
+    assert not [line for line in bind
+                if re.search(r'\b(gather|scatter)\(', line)]
