@@ -132,7 +132,10 @@ def init_state(cfg: DelegationConfig,
 def _enqueue(cfg: DelegationConfig, busy, idle, q: PairQueues):
     """Admit this slot's signals into the FCFS queues. A worker whose
     signal flips is dequeued from the opposite queue; with ``fcfs``
-    off the queues are rebuilt from the current signals (seed mode)."""
+    off the queues are rebuilt from the current signals (seed mode).
+    A worker signalled both busy and idle enters neither queue, in
+    either mode: no worker is ever queued on both sides, so no worker
+    both sheds and absorbs in one slot (``_execute`` relies on it)."""
     if cfg.fcfs:
         b = jnp.where(busy & (q.busy_since == NOT_QUEUED), q.slot,
                       q.busy_since)
@@ -141,8 +144,8 @@ def _enqueue(cfg: DelegationConfig, busy, idle, q: PairQueues):
                       q.idle_since)
         i = jnp.where(busy, NOT_QUEUED, i)
         return b, i
-    return (jnp.where(busy, q.slot, NOT_QUEUED),
-            jnp.where(idle, q.slot, NOT_QUEUED))
+    return (jnp.where(busy & ~idle, q.slot, NOT_QUEUED),
+            jnp.where(idle & ~busy, q.slot, NOT_QUEUED))
 
 
 def _fcfs_rank(enq, severity):
@@ -189,8 +192,12 @@ def _schedule(cfg: DelegationConfig, busy_rank, idle_rank, shed, absorb):
     cs = jnp.cumsum(shed[busy_rank])
     ca = jnp.cumsum(absorb[idle_rank])
     j = jnp.arange(M, dtype=jnp.int32)
-    src = busy_rank[jnp.clip(jnp.searchsorted(cs, j, side="right"), 0, last)]
-    dst = idle_rank[jnp.clip(jnp.searchsorted(ca, j, side="right"), 0, last)]
+    # compare_all: n·M compares in one fusion; the default binary search
+    # is a device loop
+    src = busy_rank[jnp.clip(jnp.searchsorted(cs, j, side="right",
+                                              method="compare_all"), 0, last)]
+    dst = idle_rank[jnp.clip(jnp.searchsorted(ca, j, side="right",
+                                              method="compare_all"), 0, last)]
     n_exec = jnp.minimum(jnp.minimum(cs[-1], ca[-1]),
                          jnp.int32(M)).astype(jnp.int32)
     return src, dst, n_exec
@@ -198,10 +205,16 @@ def _schedule(cfg: DelegationConfig, busy_rank, idle_rank, shed, absorb):
 
 def _execute(cfg: DelegationConfig, vw_owner, vw_rate, src, dst, n_exec,
              vw_bytes=None):
-    """Apply the scheduled moves: each move re-homes the source worker's
-    highest-rate VW (greatest relief). Sequential because a worker
-    shedding k VWs must pick its top-k one at a time as ownership
-    changes under it.
+    """Apply the scheduled moves, all chosen at once from the owner map
+    at the start of the slot.
+
+    Each move re-homes the highest-rate VW its source still owns
+    (greatest relief). No worker is queued both busy and idle
+    (``_enqueue``), so a source's VWs change only by its own moves, and
+    its k-th executed move takes its k-th VW in the order repeated
+    argmax would pick them: rate descending, index ascending. Every
+    count below is an integer compare-and-sum, so the result is that of
+    executing the moves one at a time, bit for bit.
 
     With ``vw_bytes`` given, moves additionally pay migration cost: a VW
     is only *eligible* if its rate amortizes its state transfer
@@ -210,34 +223,66 @@ def _execute(cfg: DelegationConfig, vw_owner, vw_rate, src, dst, n_exec,
     is left for smaller VWs later in the schedule). Skipped moves don't
     count as executed. ``vw_bytes=None`` compiles the cost-free path.
     """
-    n = cfg.n_workers
-    neg_inf = jnp.float32(-jnp.inf)
-    metered = vw_bytes is not None
-    if metered:
+    M = cfg.max_moves_per_slot
+    V = vw_owner.shape[0]
+    j = jnp.arange(M, dtype=jnp.int32)
+    u = jnp.arange(V, dtype=jnp.int32)
+    if vw_bytes is None:
+        eligible = jnp.ones((V,), bool)
+    else:
         vw_bytes = jnp.asarray(vw_bytes, jnp.float32)
-        eligible_vw = vw_rate >= cfg.min_gain_per_byte * vw_bytes
+        eligible = vw_rate >= cfg.min_gain_per_byte * vw_bytes
+    # each eligible VW's place among its owner's eligible VWs
+    ahead = ((vw_rate[:, None] > vw_rate[None, :])
+             | ((vw_rate[:, None] == vw_rate[None, :])
+                & (u[:, None] < u[None, :])))
+    rank = jnp.sum(ahead & eligible[:, None]
+                   & (vw_owner[:, None] == vw_owner[None, :]), axis=0)
+    mine = ((j < n_exec)[:, None] & eligible[None, :]
+            & (vw_owner[None, :] == src[:, None]))              # [M, V]
+    earlier = (src[None, :] == src[:, None]) & (j[None, :] < j[:, None])
+    if vw_bytes is None:
+        # every earlier move of the source took a VW, or it has none left
+        pick = mine & (rank[None, :] == jnp.sum(earlier, axis=1)[:, None])
+        n_bytes = jnp.zeros((), jnp.float32)
+    else:
+        pick, n_bytes = _metered_picks(cfg, mine, rank, earlier, vw_bytes)
+    went = jnp.any(pick, axis=1)
+    owner = jnp.where(jnp.any(pick, axis=0),
+                      jnp.sum(jnp.where(pick, dst[:, None], 0), axis=0),
+                      vw_owner).astype(vw_owner.dtype)
+    w = jnp.arange(cfg.n_workers, dtype=jnp.int32)[:, None]
+    served_src = jnp.sum(went & (src == w), axis=1, dtype=jnp.int32)
+    served_dst = jnp.sum(went & (dst == w), axis=1, dtype=jnp.int32)
+    return (owner, jnp.sum(went, dtype=jnp.int32), served_src, served_dst,
+            n_bytes)
 
-    def body(j, carry):
-        owner, done, served_src, served_dst, nbytes = carry
-        s, d = src[j], dst[j]
-        owned = owner == s
-        cand = owned & eligible_vw if metered else owned
-        v = jnp.argmax(jnp.where(cand, vw_rate, neg_inf))
-        can = (j < n_exec) & jnp.any(cand)
-        if metered and cfg.byte_budget_per_slot > 0:
-            can = can & (nbytes + vw_bytes[v] <= cfg.byte_budget_per_slot)
-        owner = owner.at[v].set(jnp.where(can, d, owner[v]).astype(owner.dtype))
-        step = can.astype(jnp.int32)
-        if metered:
-            nbytes = nbytes + jnp.where(can, vw_bytes[v], 0.0)
-        return (owner, done + step,
-                served_src.at[s].add(step), served_dst.at[d].add(step),
-                nbytes)
 
-    zeros = jnp.zeros((n,), jnp.int32)
-    return jax.lax.fori_loop(
-        0, cfg.max_moves_per_slot, body,
-        (vw_owner, jnp.int32(0), zeros, zeros, jnp.zeros((), jnp.float32)))
+def _metered_picks(cfg: DelegationConfig, mine, rank, earlier, vw_bytes):
+    """The moves of a slot that pays migration cost, in schedule order.
+
+    A move skipped for the byte budget leaves its VW with the source, so
+    the source's later moves try that VW again: move j takes its
+    source's q-th VW, q counting the source's earlier moves that
+    executed. Only the bytes so far carry from one move to the next, so
+    the M moves are unrolled over scalars, summed in schedule order.
+    """
+    M = mine.shape[0]
+    q = jnp.arange(M, dtype=jnp.int32)
+    nth = mine[:, None, :] & (rank == q[:, None])[None]         # [M, q, V]
+    has = jnp.any(nth, axis=2)
+    cost = jnp.sum(jnp.where(nth, vw_bytes, 0.0), axis=2)        # one term
+    went = jnp.zeros((M,), bool)
+    n_bytes = jnp.zeros((), jnp.float32)
+    for k in range(M):
+        qk = jnp.sum(went & earlier[k])
+        ok = has[k, qk]
+        if cfg.byte_budget_per_slot > 0:
+            ok = ok & (n_bytes + cost[k, qk] <= cfg.byte_budget_per_slot)
+        n_bytes = n_bytes + jnp.where(ok, cost[k, qk], 0.0)
+        went = went | ((q == k) & ok)
+    taken = jnp.sum(went[None, :] & earlier, axis=1)
+    return went[:, None] & mine & (rank[None, :] == taken[:, None]), n_bytes
 
 
 def seed_pairing_reference(n, max_moves, vw_load, vw_owner, util,

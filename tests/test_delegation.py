@@ -279,3 +279,157 @@ def test_random_streams_conserve_population(capacity_weighted):
         assert got.shape == (V,)
         assert got.min() >= 0 and got.max() < n
         assert np.bincount(got, minlength=n).sum() == V
+
+
+def _sequential_step(cfg, st, pressure, busy, idle, arrivals, caps,
+                     budget=None, vw_bytes=None):
+    """A NumPy specification of ``rebalance_step`` that executes the
+    scheduled moves one at a time, each taking the source's highest-rate
+    VW left (first index on ties) by argmax over the current owner map.
+    Returns the new (owner, rate, busy_since, idle_since, moves,
+    bytes_moved) and what the slot exercised."""
+    f32, nq = np.float32, int(D.NOT_QUEUED)
+    n, V, M = cfg.n_workers, cfg.n_virtual, cfg.max_moves_per_slot
+    owner = np.array(st.vw_owner)
+    rate = f32(cfg.rate_decay) * np.asarray(st.vw_rate) + arrivals
+    b0, i0 = np.asarray(st.queues.busy_since), np.asarray(st.queues.idle_since)
+    slot = int(st.queues.slot)
+    if cfg.fcfs:
+        b = np.where(idle, nq, np.where(busy & (b0 == nq), slot, b0))
+        i = np.where(busy, nq, np.where(idle & (i0 == nq), slot, i0))
+    else:
+        b = np.where(busy & ~idle, slot, nq)
+        i = np.where(idle & ~busy, slot, nq)
+
+    def rank(enq, sev):
+        order = np.argsort(np.where(enq == nq, np.inf, sev), kind="stable")
+        return order[np.argsort(enq[order], kind="stable")]
+
+    busy_rank, idle_rank = rank(b, -pressure), rank(i, pressure)
+    owned = np.bincount(owner, minlength=n)
+    one = np.minimum(owned, 1)
+    if cfg.capacity_weighted:
+        rate_w = np.bincount(owner, weights=rate, minlength=n).astype(f32)
+        total = rate_w.sum(dtype=f32)
+        target = caps / np.maximum(caps.sum(dtype=f32), f32(1e-9)) * total
+        per_vw = np.maximum(total / f32(V), f32(1e-9))
+        surplus = np.round((rate_w - target) / per_vw).astype(np.int64)
+        deficit = np.round((target - rate_w) / per_vw).astype(np.int64)
+        shed = np.where(b != nq, np.clip(surplus, one, owned), 0)
+        absorb = np.where(i != nq, np.maximum(deficit, 1), 0)
+    else:
+        shed = np.where(b != nq, one, 0)
+        absorb = (i != nq).astype(np.int64)
+    shed_cap = shed if budget is None or np.ndim(budget) == 0 else \
+        np.minimum(shed, budget)
+    cs, ca = np.cumsum(shed_cap[busy_rank]), np.cumsum(absorb[idle_rank])
+    n_exec = min(cs[-1], ca[-1], M)
+    if budget is not None and np.ndim(budget) == 0:
+        n_exec = min(n_exec, int(budget))
+    eligible = np.ones(V, bool)
+    metered = vw_bytes is not None
+    if metered:
+        eligible = rate >= f32(cfg.min_gain_per_byte) * vw_bytes
+    served_src, served_dst = np.zeros(n, np.int64), np.zeros(n, np.int64)
+    nbytes, done = f32(0.0), 0
+    seen = {"repeat_source": 0, "ran_out": 0, "over_budget": 0}
+    for j in range(n_exec):
+        s = busy_rank[min(np.searchsorted(cs, j, side="right"), n - 1)]
+        d = idle_rank[min(np.searchsorted(ca, j, side="right"), n - 1)]
+        cand = (owner == s) & eligible
+        if not cand.any():
+            seen["ran_out"] += 1
+            continue
+        v = int(np.argmax(np.where(cand, rate, -np.inf)))
+        if (metered and cfg.byte_budget_per_slot > 0
+                and nbytes + vw_bytes[v] > f32(cfg.byte_budget_per_slot)):
+            seen["over_budget"] += 1
+            continue
+        seen["repeat_source"] += int(served_src[s] > 0)
+        owner[v] = d
+        served_src[s] += 1
+        served_dst[d] += 1
+        nbytes = f32(nbytes + (vw_bytes[v] if metered else 0.0))
+        done += 1
+    b = np.where(served_src >= shed, nq, b)
+    i = np.where(served_dst >= absorb, nq, i)
+    return (owner, rate, b, i, int(st.moves) + done,
+            f32(float(st.bytes_moved) + nbytes)), seen
+
+
+# name: (config knobs, how many workers start owning no VWs, integer
+# rates, budget kind, vw_bytes, what the slots must exercise at least once)
+_SPEC_CASES = {
+    "uniform": (dict(), 0, False, None, False, ()),
+    "capacity_weighted": (dict(capacity_weighted=True, rate_decay=0.5), 0,
+                          False, None, False, ("repeat_source",)),
+    "fcfs": (dict(fcfs=True, rate_decay=0.5), 0, False, None, False, ()),
+    "fcfs_capacity_weighted": (dict(fcfs=True, capacity_weighted=True), 0,
+                               False, None, False, ("repeat_source",)),
+    "scalar_budget": (dict(capacity_weighted=True), 0, False, "scalar",
+                      False, ("repeat_source",)),
+    "per_worker_budget": (dict(capacity_weighted=True, fcfs=True), 0, False,
+                          "per_worker", False, ("repeat_source",)),
+    "integer_rate_ties": (dict(capacity_weighted=True), 0, True, None, False,
+                          ("repeat_source",)),
+    "busy_owns_nothing": (dict(), 2, False, None, False, ()),
+    "min_gain_per_byte": (dict(capacity_weighted=True, min_gain_per_byte=0.5),
+                          0, True, None, True, ("ran_out",)),
+    "byte_budget": (dict(capacity_weighted=True, byte_budget_per_slot=12.0),
+                    0, True, None, True, ("over_budget", "repeat_source")),
+}
+
+
+@pytest.mark.parametrize("case", list(_SPEC_CASES))
+def test_rebalance_step_matches_sequential_specification(case):
+    """``rebalance_step`` chooses a slot's moves in one pass; the result
+    must be that of executing them one at a time, bit for bit: owner
+    map, rates, queues, move count and bytes moved, slot after slot."""
+    knobs, n_empty, integer_rates, budget_kind, metered, must = \
+        _SPEC_CASES[case]
+    rng = np.random.default_rng(sorted(_SPEC_CASES).index(case))
+    n, a = 8, 6
+    V = n * a
+    cfg = D.DelegationConfig(n_workers=n, n_virtual=V, max_moves_per_slot=8,
+                             **knobs)
+    owner = rng.integers(n_empty, n, V).astype(np.int32)
+    st = _state(owner, n)._replace(bytes_moved=jnp.zeros((), jnp.float32))
+    caps = np.where(np.arange(n) < 2, 0.25, 1.0).astype(np.float32)
+    vw_bytes = (rng.integers(1, 8, V).astype(np.float32) if metered
+                else None)
+    seen = dict.fromkeys(("repeat_source", "ran_out", "over_budget"), 0)
+    for t in range(12):
+        if integer_rates:
+            arrivals = rng.integers(0, 4, V).astype(np.float32)
+        else:
+            arrivals = (rng.random(V) * 10).astype(np.float32)
+        load = np.bincount(np.asarray(st.vw_owner), minlength=n,
+                           weights=arrivals) / caps
+        util = (1.6 * load / max(load.max(), 1e-9)
+                + rng.random(n) * 0.3).astype(np.float32)
+        if n_empty and t == 0:
+            util[:n_empty] = 1.5          # busy, and owns no VW
+        busy, idle = util > 0.85, util < 0.75
+        budget = {"scalar": np.int32(rng.integers(0, 9)),
+                  "per_worker": rng.integers(0, 4, n).astype(np.int32),
+                  None: None}[budget_kind]
+        want, slot_seen = _sequential_step(cfg, st, util, busy, idle,
+                                           arrivals, caps, budget, vw_bytes)
+        st, moved = D.rebalance_step(
+            cfg, st, jnp.asarray(util), jnp.asarray(busy), jnp.asarray(idle),
+            jnp.asarray(arrivals), jnp.asarray(caps),
+            None if budget is None else jnp.asarray(budget),
+            None if vw_bytes is None else jnp.asarray(vw_bytes))
+        got = (np.asarray(st.vw_owner), np.asarray(st.vw_rate),
+               np.asarray(st.queues.busy_since),
+               np.asarray(st.queues.idle_since), int(st.moves),
+               np.float32(st.bytes_moved))
+        for name, g, w in zip(("owner", "rate", "busy_since", "idle_since",
+                               "moves", "bytes_moved"), got, want):
+            np.testing.assert_array_equal(g, w, err_msg=f"slot {t}: {name}")
+        for k, c in slot_seen.items():
+            seen[k] += c
+    for k in must:
+        assert seen[k], f"no slot exercised {k}: {seen}"
+    if n_empty:
+        assert int(st.moves) > 0

@@ -147,3 +147,22 @@ def test_cg_run_binds_without_gather_or_scatter(one_chip, chip_compile,
     assert bind, "no instruction carries the cg.bind scope"
     assert not [line for line in bind
                 if re.search(r'\b(gather|scatter)\(', line)]
+
+
+def test_cg_run_delegates_without_a_device_loop(one_chip, chip_compile,
+                                                monkeypatch):
+    """The slot loop's delegation step (the ``cg.delegation`` scope)
+    chooses its paired moves in one pass: a device ``while`` pays a
+    fixed cost on every trip, and a loop over the moves would make
+    ``max_moves_per_slot`` trips a slot."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    cfg = cg.CGConfig(n_workers=24, alpha=10, eps=EPS, slot_len=10_000,
+                      block_size=BLOCK, n_sources=S)
+    text = cg.run.lower(
+        cfg, _shape((2 * cfg.slot_len,), jnp.int32, one_chip),
+        _shape((cfg.n_workers,), jnp.float32, one_chip)).compile().as_text()
+    delegation = [line for line in text.splitlines()
+                  if re.search(r'op_name="[^"]*cg\.delegation[/"]', line)]
+    assert delegation, "no instruction carries the cg.delegation scope"
+    assert not [line for line in delegation
+                if re.search(r'\bwhile\(', line)]
