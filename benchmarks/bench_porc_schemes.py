@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import metrics, partitioners as P, streams
-from repro.kernels.ref import ref_porc_snapshot
+from repro.kernels.ref import ref_porc_route
 
 from .common import fmt, record, table, time_median
 
@@ -84,8 +84,9 @@ def _block_path_gate(quick: bool):
              fmt(imb_seq, 4)]]
     best = 0.0
     for B in (128, 256, 512):
-        tb, (a, load) = time_median(
-            lambda: ref_porc_snapshot(keys, n, block=B, eps=eps), reps=10)
+        tb, (a, state) = time_median(
+            lambda: ref_porc_route(keys, n, block=B, eps=eps), reps=10)
+        load = state.load
         imb = float(metrics.normalized_imbalance(a, caps))
         # capacity envelope up to block staleness (≤ B dupes per bin)
         assert float(load.max()) <= (1 + eps) * m / n + B, \

@@ -5,15 +5,7 @@ sources by SG); each source routes against its local load view — a
 shared merged base plus its own unpublished delta — synchronized by
 delta-merge every ``sync_every`` routing steps (§V-C piggybacking).
 The whole figure is one ``ref_porc_multisource`` call per point
-(vmapped across sources), so it also reports throughput; the old
-implementation looped a slow strict-cap engine over every source in
-Python and made this the slowest figure in the suite, which is why the
-100-source point used to be quarantined from quick mode.
-
-The gate section reproduces that legacy per-source loop at the gate
-point only and asserts the engine beats it ≥5× at S=50 with normalized
-imbalance within 2× — plus S=1 bit-exactness against the single-source
-block path.
+(vmapped across sources), so it also reports throughput.
 """
 from __future__ import annotations
 
@@ -21,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import metrics
-from repro.kernels.ref import ref_porc_multisource, ref_porc_route
+from repro.kernels.ref import ref_porc_multisource
 
 from .common import fmt, record, table, time_median, wp_keys
 
@@ -32,71 +24,8 @@ from .common import fmt, record, table, time_median, wp_keys
 BLOCK = 1
 
 
-def _strict_loop(keys_np: np.ndarray, s: int, vws: int, eps: float):
-    """The legacy Fig-11 path: one strict-cap engine call per source,
-    fully independent load views, Python loop."""
-    m = len(keys_np)
-    assign_vw = np.empty(m, np.int32)
-    for i in range(s):
-        a, _ = ref_porc_route(jnp.asarray(keys_np[i::s]), vws, eps=eps,
-                              engine="strict")
-        assign_vw[i::s] = np.asarray(a)
-    return jnp.asarray(assign_vw)
-
-
-def _gate(keys, m: int, eps: float, quick: bool):
-    """Exactness + speed/imbalance gate vs the legacy per-source loop."""
-    # (a) S=1, sync_every=1 must be bit-identical to the single-source
-    # block path (any block size; use the runtime default 128)
-    short = keys[:8192]
-    a_ref, _ = ref_porc_route(short, 100, block=128, eps=eps)
-    a_ms, _ = ref_porc_multisource(short, 100, 1, sync_every=1, block=128,
-                                   eps=eps)
-    ms1_exact = bool((np.asarray(a_ref) == np.asarray(a_ms)).all())
-    assert ms1_exact, "multisource S=1 diverged from ref_porc_route"
-
-    # (b) ≥5x over the looped strict path at S=50, imbalance within 2x
-    n, vws = 10, 100
-    caps = jnp.ones(n) / n
-    keys_np = np.asarray(keys)
-    rows = []
-    min_speedup = None
-    for s in (50,) if quick else (50, 100):
-        t_loop, a_loop = time_median(
-            lambda: _strict_loop(keys_np, s, vws, eps), reps=1)
-        imb_loop = float(metrics.normalized_imbalance(
-            jnp.asarray(np.asarray(a_loop) % n, jnp.int32), caps))
-        t_ms, (a_ms, _) = time_median(
-            lambda: ref_porc_multisource(keys, vws, s, sync_every=1,
-                                         block=BLOCK, eps=eps), reps=3)
-        imb_ms = float(metrics.normalized_imbalance(
-            jnp.asarray(np.asarray(a_ms) % n, jnp.int32), caps))
-        speedup = t_loop / t_ms
-        ratio = imb_ms / max(imb_loop, 1e-9)
-        record("sources", section="gate", sources=s, n_workers=n, m=m,
-               loop_s=t_loop, engine_s=t_ms, speedup=speedup,
-               imbalance_loop=imb_loop, imbalance_engine=imb_ms,
-               imbalance_ratio=ratio, ms1_exact=ms1_exact)
-        rows.append([s, fmt(t_loop * 1e3, 1), fmt(t_ms * 1e3, 1),
-                     fmt(speedup, 1), fmt(imb_loop, 4), fmt(imb_ms, 4),
-                     fmt(ratio, 2)])
-        assert speedup >= 5.0, \
-            f"multisource engine too slow at S={s}: {speedup:.1f}x < 5x"
-        assert ratio <= 2.0, \
-            f"multisource imbalance off envelope at S={s}: {ratio:.2f}x > 2x"
-        if min_speedup is None or speedup < min_speedup:
-            min_speedup = speedup
-    print(table(f"Gate — multisource engine vs legacy per-source loop "
-                f"(m={m}, {vws} VWs, eps={eps})",
-                ["sources", "loop ms", "engine ms", "speedup",
-                 "imb loop", "imb engine", "ratio"], rows))
-    record("sources", section="gate_summary", ms1_exact=ms1_exact,
-           min_speedup=min_speedup)
-
-
 def run(m: int = 128_000, quick: bool = False):
-    # all source counts run in both modes — the engine un-quarantines
-    # the 100-source point that the per-source loop made too slow
+    # all source counts run in both modes
     srcs = (1, 10, 50, 100)
     ns = (10, 50) if quick else (5, 10, 50, 100)
     if quick:
@@ -145,7 +74,6 @@ def run(m: int = 128_000, quick: bool = False):
                 ["sources", "sync_every", "window msgs", "imbalance",
                  "M msg/s"], rows))
 
-    _gate(keys, m, eps=0.01, quick=quick)
     print("paper-claim check: imbalance and memory stay flat (log scale) "
           "as sources grow 1→100 — local load views suffice")
 

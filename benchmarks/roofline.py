@@ -203,10 +203,10 @@ _FIX_NOTES = {
 
 def _measured_porc(quick: bool):
     """Measured routing roofline: per-(block, n_bins, scheme) cells on
-    the WP trace, three engines each —
+    the WP trace, the one PoRC block engine two ways each —
 
-      oracle      rank-sequential strict-cap PoRC (``ref_porc_assign``)
-      jnp-block   snapshot-probing block engine (``ref_porc_snapshot``)
+      jnp-block   the jnp scan (``ref_porc_route``: the multi-source
+                  engine at S=1)
       pallas      the same block engine as a Pallas kernel with the
                   load vector in VMEM and candidate hashing fused into
                   the probe scan (``kernels.porc_snapshot``)
@@ -222,65 +222,40 @@ def _measured_porc(quick: bool):
     """
     import numpy as np
 
-    from repro.kernels import porc_snapshot
     from repro.kernels.blocks import HHPolicy
-    from repro.kernels.ref import (ref_porc_assign, ref_porc_route,
-                                   ref_porc_snapshot)
+    from repro.kernels.ref import ref_porc_route
 
     backend = jax.default_backend()
     eps = 0.05
-    # the sequential oracle is ~1.2 k msgs/s on CPU — keep M small
-    # enough that the measured rows cost seconds, not minutes
     M = 8192 if quick else 65536
     cells = [(512, 1024)] if quick else [(512, 1024), (128, 256)]
     keys = wp_keys(M)
     rows = []
-    for block, n_bins in cells:
-        t_oracle, _ = time_median(
-            lambda: ref_porc_assign(keys, n_bins, block=block, eps=eps))
-        t_block, (a_block, _) = time_median(
-            lambda: ref_porc_snapshot(keys, n_bins, block=block, eps=eps))
-        t_pal, (a_pal, _) = time_median(
-            lambda: porc_snapshot(keys, n_bins, block=block, eps=eps))
-        exact = bool((np.asarray(a_block) == np.asarray(a_pal)).all())
-        record("roofline", scenario="porc_engines", scheme="porc",
-               backend=backend, n_msgs=M, n_bins=n_bins, block=block,
-               oracle_msgs_per_sec=M / t_oracle,
-               block_msgs_per_sec=M / t_block,
-               block_over_oracle=t_oracle / t_block,
-               pallas_msgs_per_sec=M / t_pal,
-               pallas_over_block=t_block / t_pal,
-               pallas_over_oracle=t_oracle / t_pal,
-               pallas_exact=exact)
-        rows.append(["porc", block, n_bins, fmt(M / t_oracle, 0),
-                     fmt(M / t_block, 0), fmt(M / t_pal, 0),
-                     fmt(t_block / t_pal, 2), exact])
     # W-Choices cell: the HH policy path, where the Pallas kernel also
     # fuses the count-min sketch update + budget lookup into the scan.
-    # No sequential oracle exists (probe budgets are sketch-defined).
-    block, n_bins = cells[0]
-    pol = HHPolicy(scheme="w", width=1024)
-    t_w, (a_w, _) = time_median(
-        lambda: ref_porc_route(keys, n_bins, block=block, eps=eps,
-                               policy=pol))
-    t_wp, (a_wp, _) = time_median(
-        lambda: ref_porc_route(keys, n_bins, block=block, eps=eps,
-                               policy=pol, engine="pallas"))
-    exact = bool((np.asarray(a_w) == np.asarray(a_wp)).all())
-    record("roofline", scenario="porc_engines", scheme="wchoices",
-           backend=backend, n_msgs=M, n_bins=n_bins, block=block,
-           block_msgs_per_sec=M / t_w,
-           pallas_msgs_per_sec=M / t_wp,
-           pallas_over_block=t_w / t_wp,
-           pallas_exact=exact)
-    rows.append(["wchoices", block, n_bins, "-", fmt(M / t_w, 0),
-                 fmt(M / t_wp, 0), fmt(t_w / t_wp, 2), exact])
+    schemes = [("porc", cell, None) for cell in cells] + [
+        ("wchoices", cells[0], HHPolicy(scheme="w", width=1024))]
+    for scheme, (block, n_bins), pol in schemes:
+        t_block, (a_block, _) = time_median(
+            lambda: ref_porc_route(keys, n_bins, block=block, eps=eps,
+                                   policy=pol))
+        t_pal, (a_pal, _) = time_median(
+            lambda: ref_porc_route(keys, n_bins, block=block, eps=eps,
+                                   policy=pol, engine="pallas"))
+        exact = bool((np.asarray(a_block) == np.asarray(a_pal)).all())
+        record("roofline", scenario="porc_engines", scheme=scheme,
+               backend=backend, n_msgs=M, n_bins=n_bins, block=block,
+               block_msgs_per_sec=M / t_block,
+               pallas_msgs_per_sec=M / t_pal,
+               pallas_over_block=t_block / t_pal,
+               pallas_exact=exact)
+        rows.append([scheme, block, n_bins, fmt(M / t_block, 0),
+                     fmt(M / t_pal, 0), fmt(t_block / t_pal, 2), exact])
     mode = "compiled" if backend == "tpu" else "interpret"
     print(table(f"§Roofline — measured PoRC engines (WP trace, "
                 f"backend={backend}, pallas={mode})",
-                ["scheme", "block", "n_bins", "oracle msg/s",
-                 "jnp-block msg/s", "pallas msg/s", "pallas/jnp",
-                 "exact"], rows))
+                ["scheme", "block", "n_bins", "jnp-block msg/s",
+                 "pallas msg/s", "pallas/jnp", "exact"], rows))
 
 
 def run(quick: bool = False, results_dir: str = "results/dryrun"):

@@ -39,10 +39,11 @@ Model fidelity notes
   an unbounded FIFO — the queueing model of §IV used for Fig 9/10/12/13.
 * **Block-parallel routing** (``block_size``): the paper defines PoRC
   one-message-per-unit-time; the runtime routes ``block_size`` messages
-  per load snapshot (``repro.kernels.ref.ref_porc_snapshot``) — the
-  §V-C eventual-consistency license, same as sources with local load
-  views. ``block_size=0`` keeps the exact per-message oracle;
-  ``block_size=1`` takes the block path and is bit-identical to it.
+  per load snapshot (``repro.kernels.ref.ref_porc_multisource``, the
+  one block engine) — the §V-C eventual-consistency license, same as
+  sources with local load views. ``block_size=0`` keeps the exact
+  per-message oracle; ``block_size=1`` takes the block path and is
+  bit-identical to it.
 * **Distributed sources** (``n_sources``/``sync_every``): §V-C's
   multiple sources become first-class — the slot's stream splits
   round-robin across ``n_sources`` sources, each routing against a
@@ -50,7 +51,7 @@ Model fidelity notes
   ``sync_every`` blocks. The slot boundary (the monitoring period t₀)
   forces a final merge: that is when the piggybacked signals all
   arrive, so no unpublished delta survives into the next slot.
-  ``n_sources=1`` is exactly the single-source block path.
+  ``n_sources=1`` is single-source routing: the one view is the load.
 * **Heavy-hitter probing** (``hh_scheme``): D/W-Choices probe depths for
   the PORC inner scheme — a count-min sketch (``sketch_depth`` ×
   ``sketch_width``, carried in ``CGState.sketch``) classifies keys at
@@ -128,13 +129,14 @@ class CGConfig(NamedTuple):
     hh_headroom: float = 2.0      # probe-depth schedule slack over the
                                   # Eq.-2 spread ceil(p·n/(1+eps))
     engine: str = "auto"          # block-engine implementation for the
-                                  # PORC inner scheme: "ref" (jnp scan),
-                                  # "pallas" (Pallas kernel, bit-identical
-                                  # — load/delta/sketch lanes in VMEM),
-                                  # "auto" = Pallas on TPU, jnp elsewhere.
-                                  # Applies to the block path only; the
-                                  # block_size=0 sequential oracle and
-                                  # KG/SG ignore it.
+                                  # PORC inner scheme, resolved by
+                                  # kernels.resolve_engine: "ref" (jnp
+                                  # scan), "pallas" (Pallas kernel,
+                                  # bit-identical — base/delta/sketch
+                                  # lanes in VMEM), "auto" = Pallas on
+                                  # TPU, jnp elsewhere. Applies to the
+                                  # block path only; the block_size=0
+                                  # sequential oracle and KG/SG ignore it.
 
 
 class CGState(NamedTuple):
@@ -285,46 +287,28 @@ def _route_slot(cfg: CGConfig, vw_load, t_offset, sg_ptr, sketch, keys):
         vw_load = vw_load.at[vw].add(1.0)
         return vw_load, sketch, vw
 
-    if cfg.n_sources > 1:
-        # §V-C distributed sources: the slot's stream splits round-robin
-        # across n_sources local load views (shared merged base + own
-        # delta, synchronized every sync_every blocks). The slot end is
-        # the monitoring boundary, where piggybacked deltas all arrive —
+    if cfg.block_size >= 1:
+        # Block-parallel PoRC (§V-C distributed sources; one source is
+        # S=1): the slot's stream splits round-robin across n_sources
+        # local load views (shared merged base + own delta, synchronized
+        # every sync_every blocks), each routing blocks of block_size
+        # against its view. Bit-identical to the sequential path below
+        # at n_sources == block_size == 1. The slot end is the
+        # monitoring boundary, where piggybacked deltas all arrive —
         # merge them so CGState keeps a single [V] load vector.
-        if cfg.block_size < 1:
-            raise ValueError("n_sources > 1 requires the block path "
-                             "(block_size >= 1)")
-        from repro.kernels.ref import (MultiSourcePorcState,
-                                       ref_porc_multisource)
-        state = MultiSourcePorcState(
-            base=vw_load,
-            delta=jnp.zeros((cfg.n_sources, V), jnp.float32),
-            routed=t_offset,
-            ticks=jnp.zeros((), jnp.int32),
-            sketch_base=sketch,
-            sketch_delta=None if sketch is None else jnp.zeros(
-                (cfg.n_sources,) + sketch.shape, jnp.float32))
         from repro.kernels import resolve_engine
+        from repro.kernels.ref import (multisource_merge, published_state,
+                                       ref_porc_multisource)
         vw, state = ref_porc_multisource(
             keys, V, cfg.n_sources, sync_every=cfg.sync_every,
-            block=cfg.block_size, eps=cfg.eps, state=state, policy=policy,
-            engine=resolve_engine(cfg.engine, policy))
-        sketch = (None if state.sketch_base is None
-                  else state.sketch_base + state.sketch_delta.sum(0))
-        return state.base + state.delta.sum(0), sketch, vw
-
-    if cfg.block_size >= 1:
-        # Block-parallel PoRC: route the slot in blocks of B messages
-        # against per-block load snapshots (eventually-consistent, the
-        # kernels' block-synchronous semantics). Bit-identical to the
-        # sequential path below when block_size == 1.
-        from repro.kernels import resolve_engine
-        from repro.kernels.ref import PorcState, ref_porc_route
-        state = PorcState(load=vw_load, routed=t_offset, sketch=sketch)
-        vw, state = ref_porc_route(keys, V, block=cfg.block_size,
-                                   eps=cfg.eps, state=state, policy=policy,
-                                   engine=resolve_engine(cfg.engine, policy))
-        return state.load, state.sketch, vw
+            block=cfg.block_size, eps=cfg.eps,
+            state=published_state(vw_load, t_offset, cfg.n_sources, sketch),
+            policy=policy, engine=resolve_engine(cfg.engine, policy))
+        state = multisource_merge(state)
+        return state.base, state.sketch_base, vw
+    if cfg.n_sources > 1:
+        raise ValueError("n_sources > 1 requires the block path "
+                         "(block_size >= 1)")
 
     # PoRC (Alg. 1) continuing across slots: capacity uses global time.
     max_probes = 4 * V

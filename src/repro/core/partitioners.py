@@ -210,15 +210,12 @@ def power_of_random_choices_blocked(keys: jnp.ndarray, n_bins: int,
                                     block: int = 128,
                                     engine: str = "ref") -> jnp.ndarray:
     """Batched PoRC: Alg. 1 against a per-block load snapshot, capacity
-    evaluated at the block boundary. Delegates to the kernel block
-    engine (``repro.kernels.ref``), which carries state across blocks.
-    ``engine``: "ref" (jnp scan) | "pallas" (Pallas kernel, bit-identical)
-    | "auto" (Pallas on TPU, jnp elsewhere)."""
-    from repro.kernels import resolve_engine  # deferred: core ← kernels
-    from repro.kernels.ref import ref_porc_route
-    assign, _ = ref_porc_route(keys, n_bins, block=block, eps=eps,
-                               engine=resolve_engine(engine))
-    return assign
+    evaluated at the block boundary — the kernel block engine
+    (``repro.kernels.ref``) at one source, carrying state across
+    blocks. ``engine``: "ref" (jnp scan) | "pallas" (Pallas kernel,
+    bit-identical) | "auto" (Pallas on TPU, jnp elsewhere)."""
+    return power_of_random_choices_multisource(keys, n_bins, 1, eps=eps,
+                                               block=block, engine=engine)
 
 
 def power_of_random_choices_multisource(keys: jnp.ndarray, n_bins: int,
@@ -230,11 +227,11 @@ def power_of_random_choices_multisource(keys: jnp.ndarray, n_bins: int,
     """Multi-source PoRC (§V-C): the stream splits round-robin across
     ``n_sources`` sources, each routing blocks against its local load
     view (shared merged base + own unpublished delta); views synchronize
-    by delta-merge every ``sync_every`` blocks. ``n_sources=1,
-    sync_every=1`` is bit-identical to the blocked single-source path.
-    ``hh`` (an ``HHPolicy``) turns on heavy-hitter-aware probe depths;
-    the per-source sketch deltas merge on the same sync cadence.
-    ``engine`` selects the block engine ("ref" | "pallas" | "auto")."""
+    by delta-merge every ``sync_every`` blocks. ``n_sources=1`` is the
+    blocked single-source path. ``hh`` (an ``HHPolicy``) turns on
+    heavy-hitter-aware probe depths; the per-source sketch deltas merge
+    on the same sync cadence. ``engine`` selects the block engine
+    ("ref" | "pallas" | "auto")."""
     from repro.kernels import resolve_engine  # deferred: core ← kernels
     from repro.kernels.ref import ref_porc_multisource
     assign, _ = ref_porc_multisource(keys, n_bins, n_sources,
@@ -249,14 +246,13 @@ def power_of_random_choices_multisource(keys: jnp.ndarray, n_bins: int,
 # ---------------------------------------------------------------------------
 
 def _hh_choices(keys: jnp.ndarray, n_bins: int, scheme: str, eps: float,
-                block: int, hh, engine: str = "ref") -> jnp.ndarray:
-    from repro.kernels import resolve_engine  # deferred: core ← kernels
-    from repro.kernels.ref import HHPolicy, ref_porc_route
+                block: int, hh, engine: str = "ref", sources: int = 1,
+                sync_every: int = 1) -> jnp.ndarray:
+    from repro.kernels.ref import HHPolicy  # deferred: core ← kernels
     policy = HHPolicy(scheme=scheme) if hh is None else hh._replace(scheme=scheme)
-    assign, _ = ref_porc_route(keys, n_bins, block=block, eps=eps,
-                               policy=policy,
-                               engine=resolve_engine(engine, policy))
-    return assign
+    return power_of_random_choices_multisource(
+        keys, n_bins, sources, eps=eps, block=block, sync_every=sync_every,
+        hh=policy, engine=engine)
 
 
 def d_choices(keys: jnp.ndarray, n_bins: int, eps: float = 0.01,
@@ -387,16 +383,9 @@ def route(scheme: str, keys: jnp.ndarray, n_bins: int, *,
         raise ValueError("engine applies to the block path — pass "
                          "block_size (the sequential oracle is jnp-only)")
     if scheme in HH_SCHEMES:
-        from repro.kernels.ref import HHPolicy  # deferred: core ← kernels
-        letter = "d" if scheme == "DCHOICES" else "w"
-        if sources > 1:
-            policy = (HHPolicy(scheme=letter) if hh is None
-                      else hh._replace(scheme=letter))
-            return power_of_random_choices_multisource(
-                keys, n_bins, sources, eps=eps, block=block_size or 128,
-                sync_every=sync_every, hh=policy, engine=engine)
-        return _hh_choices(keys, n_bins, letter, eps, block_size or 128, hh,
-                           engine)
+        return _hh_choices(keys, n_bins, "d" if scheme == "DCHOICES" else "w",
+                           eps, block_size or 128, hh, engine, sources,
+                           sync_every)
     if scheme == "KG":
         return key_grouping(keys, n_bins)
     if scheme == "SG":
@@ -410,14 +399,10 @@ def route(scheme: str, keys: jnp.ndarray, n_bins: int, *,
             return power_of_two_choices_blocked(keys, n_bins, block=block_size)
         return power_of_two_choices(keys, n_bins)
     if scheme == "PORC":
-        if sources > 1:
+        if block_size or sources > 1:
             return power_of_random_choices_multisource(
                 keys, n_bins, sources, eps=eps, block=block_size or 128,
                 sync_every=sync_every, engine=engine)
-        if block_size:
-            return power_of_random_choices_blocked(keys, n_bins, eps=eps,
-                                                   block=block_size,
-                                                   engine=engine)
         return power_of_random_choices(keys, n_bins, eps=eps)
     if scheme == "CH":
         return consistent_hashing_bounded(keys, n_bins, eps=eps)

@@ -9,15 +9,15 @@ Two knobs resolve here:
   ``interpret=True`` so they exercise the kernel path on any backend).
 * ``engine`` — the user-facing routing-engine selector
   (``partitioners.route``, ``CGConfig.engine``,
-  ``serve.CGRequestRouter``): ``"ref"`` is the jnp block engine,
-  ``"pallas"`` the Pallas block engine, ``"auto"`` picks Pallas on TPU
-  and jnp elsewhere (on CPU the interpreted kernel is strictly slower
-  than the jnp scan — same math, per-op interpreter overhead — so auto
-  never pays it). Heavy-hitter policy traffic is the exception: its
-  kernel body does not lower to Mosaic, so ``"auto"`` with a policy
-  names the jnp engine on every platform. The internal names
-  ``"snapshot"``/``"strict"`` pass through for callers addressing
-  ``kernels.ref`` directly.
+  ``serve.CGRequestRouter``), resolved once at that boundary:
+  ``"ref"`` is the jnp block engine, ``"pallas"`` the Pallas block
+  engine, ``"auto"`` picks Pallas on TPU and jnp elsewhere (on CPU the
+  interpreted kernel is strictly slower than the jnp scan — same math,
+  per-op interpreter overhead — so auto never pays it). Heavy-hitter
+  policy traffic is the exception: its kernel body does not lower to
+  Mosaic, so ``"auto"`` with a policy names the jnp engine on every
+  platform. The kernels layer (``ref.ref_porc_multisource``) takes the
+  resolved ``"ref"`` or ``"pallas"``.
 """
 from __future__ import annotations
 
@@ -34,14 +34,12 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 def resolve_engine(engine: str, policy=None) -> str:
-    """Map an engine knob to the concrete block engine to run for
-    traffic routed with ``policy`` (an ``HHPolicy`` or None)."""
-    if engine in ("ref", "jnp"):
-        return "snapshot"
+    """Map an engine knob to the block engine (``"ref"`` or
+    ``"pallas"``) that runs traffic routed with ``policy`` (an
+    ``HHPolicy`` or None)."""
     if engine == "auto":
-        return "pallas" if on_tpu() and policy is None else "snapshot"
-    if engine in ("snapshot", "strict", "pallas"):
+        return "pallas" if on_tpu() and policy is None else "ref"
+    if engine in ("ref", "pallas"):
         return engine
     raise ValueError(
-        f"unknown engine {engine!r}: expected 'ref' | 'pallas' | 'auto' "
-        "(or the internal 'snapshot' | 'strict')")
+        f"unknown engine {engine!r}: expected 'ref' | 'pallas' | 'auto'")

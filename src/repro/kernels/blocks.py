@@ -4,8 +4,8 @@ Everything here is the snapshot-probing block engine's inner math —
 candidate resolution against a frozen load snapshot, the heavy-hitter
 budget schedule, the count-min sketch, and the capacity schedule — in a
 form that traces identically inside a ``jax.lax.scan`` body (the jnp
-reference engines in ``kernels/ref.py``) and inside a Pallas kernel
-body (``kernels/porc_snapshot.py``). The Pallas engines call these
+reference engine in ``kernels/ref.py``) and inside a Pallas kernel
+body (``kernels/porc_snapshot.py``). The Pallas engine calls these
 exact functions, which is what makes kernel-vs-ref bit-identity a
 structural property instead of a test-enforced aspiration.
 
@@ -44,12 +44,6 @@ def probe_salts(count: int, start: int = 1) -> jnp.ndarray:
 # Both engines must evaluate the *same float expression* — float32
 # addition/division are not associative, so a mathematically equal
 # rearrangement would break bit-identity between ref and kernel.
-
-def snapshot_cap(eps: float, n_bins: int, m0, b, block: int):
-    """Single-source capacity at the end of block ``b``:
-    (1+eps)·m_t/n with m_t = m0 + (b+1)·block."""
-    return (1.0 + eps) * (m0 + (b + 1.0) * block) / n_bins
-
 
 def view_cap(eps: float, n_bins: int, mass, lookahead: float):
     """Per-source capacity from the local-view mass (multisource §V-C):
@@ -134,9 +128,9 @@ def snapshot_block(load, cap, kblk, cand0, n_bins: int, block: int,
                    chunk: int, table: Table = GATHER):
     """Route one block of keys against a frozen load snapshot.
 
-    The single routing semantics shared by ``ref_porc_snapshot`` (one
-    source, snapshot = running load) and ``ref_porc_multisource`` (one
-    snapshot per source = merged base + own delta): each key walks its
+    The routing semantics of ``ref_porc_multisource`` and its Pallas
+    kernel, one call per source (snapshot = merged base + own delta;
+    with one source, the running load): each key walks its
     salted-probe chain against ``load`` and stops at the first bin below
     ``cap``. At block=1 the full 4·n_bins chain of Alg. 1 runs (lazily,
     in chunks of ``chunk`` salts); at block>1 the budget is the ``chunk``
@@ -371,3 +365,64 @@ def snapshot_block_hh(load, cap, kblk, cand, bud, n_bins: int,
         globpick = jnp.broadcast_to(jnp.argmin(load).astype(jnp.int32), (B,))
     fallback = jnp.where(over, globpick, candmin)
     return jnp.where(resolved, pick, fallback)
+
+
+# ---------------------------------------------------------------------------
+# One block of every source lane (§V-C local views)
+# ---------------------------------------------------------------------------
+
+def route_lanes(base, delta, skb, skd, kblk, cblk, *, n_bins: int,
+                n_sources: int, block: int, eps: float, chunk: int,
+                policy: HHPolicy | None = None, weights=None):
+    """Route one block per source lane against its local view and count
+    it into the lane.
+
+    ``base`` [n_bins] is the merged load, ``delta`` [S', n_bins] the
+    lanes' unpublished counts and ``kblk`` [S', block] their keys; the
+    lanes may be a shard of the ``n_sources`` sources. ``cblk`` [S',
+    block, C] are the hoisted salted candidates, or None to hash them
+    here. Each lane's capacity comes from the mass of its *local view*
+    (merged base + own delta), not the global clock: a cap the source
+    cannot verify against its view would let all S sources fill a hot
+    bin to the global cap independently (S× overshoot at cold start);
+    the local-mass cap keeps the per-source invariant load_view ≤
+    (1+eps)·mass_view/n, whose sum telescopes to the global
+    (1+eps)·m/n envelope — exactly why the paper's independent-sources
+    argument works. The arriving block enters the mass as block/S, so
+    the *aggregate* lookahead across sources is one block, matching a
+    single source's m_t at block end.
+
+    With a ``policy`` each lane classifies its block against its local
+    sketch view ``skb + skd[s]`` at the block boundary, routes with
+    per-key probe budgets and counts the block into its sketch delta.
+    ``weights`` [S', block] (default all ones) scales what each message
+    counts, so a masked message routes without being counted.
+
+    Returns (assign [S', block] int32, delta, skd).
+    """
+    mass = count_sum(base) + count_sum(delta, 1)          # [S'] local view
+    cap = view_cap(eps, n_bins, mass, block / n_sources)
+    views = base[None, :] + delta                         # [S', n_bins]
+    if cblk is None:
+        chunk_eff = chunk if policy is None else hh_chunk(policy, chunk,
+                                                          n_bins)
+        cblk = hash_to_bins(kblk[..., None], probe_salts(chunk_eff), n_bins)
+    if policy is None:
+        assign = jax.vmap(
+            lambda view, c, kk, cb: snapshot_block(
+                view, c, kk, salt_columns(cb), n_bins, block, chunk))(
+            views, cap, kblk, cblk)
+    else:
+        est = jax.vmap(lambda d, k: hh_sketch_query(policy, skb + d, k))(
+            skd, kblk)                                    # [S', block]
+        bud = hh_budgets(policy, n_bins, eps, est, mass[:, None])
+        assign = jax.vmap(
+            lambda view, c, kk, cb, bd: snapshot_block_hh(
+                view, c, kk, cb, bd, n_bins, policy.rotate_duplicates,
+                policy.spread_fallback))(views, cap, kblk, cblk, bud)
+        skd = jax.vmap(
+            lambda d, k, w: hh_sketch_update(policy, d, k, weights=w))(
+            skd, kblk, weights)
+    lane = jax.lax.broadcasted_iota(jnp.int32, assign.shape, 0)
+    delta = delta.at[lane, assign].add(1.0 if weights is None else weights)
+    return assign, delta, skd

@@ -5,34 +5,15 @@ container, unit tests) they run in interpret mode, which executes the
 kernel body with real JAX ops — same semantics, validated against the
 ``ref`` oracles. The backend choice lives in the kernels themselves
 now (``interpret=None`` → auto, see ``kernels.backend``); these
-wrappers just re-export the auto-mode call.
+wrappers just re-export the auto-mode call. The PoRC block engine is
+driven through ``ref.ref_porc_multisource(engine="pallas")``.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 from .cg_dispatch import cg_dispatch as _cg_dispatch
-from .porc_assign import porc_assign as _porc_assign
-from .porc_snapshot import porc_snapshot as _porc_snapshot
 from .ssd_scan import ssd_scan as _ssd_scan
-
-
-def porc_assign(keys: jnp.ndarray, n_bins: int, *, d: int | None = None,
-                block: int = 128, eps: float = 0.05, m0: float = 0.0,
-                load0: jnp.ndarray | None = None):
-    """Block-synchronous PoRC routing (paper Alg. 1, TPU-adapted):
-    the rank-sequential strict-cap kernel."""
-    return _porc_assign(keys, n_bins, d=d, block=block, eps=eps, m0=m0,
-                        load0=load0)
-
-
-def porc_snapshot(keys: jnp.ndarray, n_bins: int, *, block: int = 128,
-                  eps: float = 0.05, chunk: int = 8, m0: float = 0.0,
-                  load0: jnp.ndarray | None = None):
-    """Snapshot-probing PoRC block engine (the fast path) as a Pallas
-    kernel — bit-identical to ``ref.ref_porc_snapshot``."""
-    return _porc_snapshot(keys, n_bins, block=block, eps=eps, chunk=chunk,
-                          m0=m0, load0=load0)
 
 
 def cg_dispatch(pref: jnp.ndarray, gates: jnp.ndarray, *, n_experts: int,

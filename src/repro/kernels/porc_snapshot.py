@@ -1,41 +1,39 @@
-"""Pallas snapshot-probing PoRC block engine (single- and multi-source).
+"""Pallas snapshot-probing PoRC block engine (one kernel, S source lanes).
 
-The fast-path semantics of ``ref.ref_porc_snapshot`` /
-``ref.ref_porc_multisource`` as sequential-grid Pallas kernels: the
-load vector (and, multisource, the per-source delta lanes and count-min
-sketch lanes) lives in **VMEM scratch** and is carried across the grid,
+The semantics of ``ref.ref_porc_multisource`` as a sequential-grid
+Pallas kernel; one source is the S=1 case. The merged load, the
+per-source delta lanes and, with a heavy-hitter policy, the count-min
+sketch lanes live in **VMEM scratch** and are carried across the grid,
 so per block the only HBM traffic is the keys in and the assignments
 out. Candidate hashing is *fused into the probe scan* — the salted
 chain is hashed inside the kernel body right before it is resolved
 against the snapshot, instead of materializing a [M, chain] candidate
 tensor in HBM the way the jnp path hoists it. That fusion is what
-removes the ROADMAP-flagged chain-width cost of the HH policy path: a
-W-Choices chain of n_bins candidates never round-trips to memory.
+removes the chain-width cost of the HH policy path: a W-Choices chain
+of n_bins candidates never round-trips to memory.
 
-Bit-identity with the jnp reference engines is structural, not
-aspirational: the kernel bodies call the *same* block math
+Bit-identity with the jnp reference engine is structural, not
+aspirational: the kernel body calls the *same* block math
 (``kernels.blocks``: ``snapshot_block``, the order-independent
-``count_sum`` and the shared capacity schedule
-``snapshot_cap``/``view_cap``) that ``kernels/ref.py`` scans over, and
-the hash family in ``core.hashing`` is written to trace inside a kernel
-body. Only table access differs: Mosaic has no vector gather or
-scatter, so the kernels read and count bins by compare-and-select
-against a bin iota (``ONEHOT``), which is exact for integer-valued
-counts. Layout follows the TPU's tiles: keys ride the lanes (a block
-is a ``[1, block]`` row, or ``[S, block]`` for S sources), per-bin
-vectors ride the sublanes (``[n_bins, 1]`` columns; the S delta lanes
-are ``[n_bins, S]``), and the source lanes are a static loop. The
-parity tests (``tests/test_porc_snapshot_pallas.py``) pin this in
-interpret mode; ``tests/test_tpu_compile.py`` compiles it for v5e.
+``count_sum`` and the capacity schedule ``view_cap``) that
+``kernels/ref.py`` scans over, and the hash family in ``core.hashing``
+is written to trace inside a kernel body. Only table access differs:
+Mosaic has no vector gather or scatter, so the kernel reads and counts
+bins by compare-and-select against a bin iota (``ONEHOT``), which is
+exact for integer-valued counts. Layout follows the TPU's tiles: keys
+ride the lanes (a block is an ``[S, block]`` tile), per-bin vectors
+ride the sublanes (``[n_bins, 1]`` columns; the S delta lanes are
+``[n_bins, S]``), and the source lanes are a static loop. The parity
+tests (``tests/test_porc_snapshot_pallas.py``) pin this in interpret
+mode; ``tests/test_tpu_compile.py`` compiles it for v5e.
 
-The heavy-hitter policy path of the multi-source kernel (count-min
-sketch lanes, per-key budgets) still uses gathers, sorts and prefix
-sums that Mosaic does not lower; it runs in interpret mode only, and
-``backend.resolve_engine("auto")`` routes policy traffic to the jnp
-engine.
+The heavy-hitter policy path (count-min sketch lanes, per-key budgets)
+still uses gathers, sorts and prefix sums that Mosaic does not lower;
+it runs in interpret mode only, and ``backend.resolve_engine("auto")``
+routes policy traffic to the jnp engine.
 
-Grid: (M // block,), sequential. Scratch: load [n_bins, 1] f32 (+
-delta [n_bins, S], sketch lanes when multisource / HH policy).
+Grid: (M // (S·block),), sequential. Scratch: base [n_bins, 1] f32,
+delta [n_bins, S] f32 (+ sketch lanes under an HH policy).
 """
 from __future__ import annotations
 
@@ -96,83 +94,8 @@ _SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 # ---------------------------------------------------------------------------
-# Single source — the ``ref_porc_snapshot`` kernel
-# ---------------------------------------------------------------------------
-
-def _snapshot_kernel(m0_ref, load0_ref, keys_ref, assign_ref, loadout_ref,
-                     load_scr, *,
-                     n_bins: int, block: int, eps: float, chunk: int,
-                     n_blocks: int):
-    b = pl.program_id(0)
-
-    @pl.when(b == 0)
-    def _init():
-        load_scr[...] = load0_ref[...]
-
-    load = load_scr[...]                               # [n_bins, 1]
-    kblk = keys_ref[...]                               # [1, block]
-    cap = blocks.snapshot_cap(eps, n_bins, m0_ref[0],
-                              b.astype(jnp.float32), block)
-    assign = blocks.snapshot_block(load, cap, kblk,
-                                   _salted(kblk, chunk, n_bins), n_bins,
-                                   block, chunk, ONEHOT)
-    assign_ref[...] = assign
-    load_scr[...] = _count(load, assign)
-
-    @pl.when(b == n_blocks - 1)
-    def _flush():
-        loadout_ref[...] = load_scr[...]
-
-
-@functools.partial(jax.jit, static_argnames=("n_bins", "block", "eps",
-                                             "chunk", "interpret"))
-def porc_snapshot(keys: jnp.ndarray, n_bins: int, *, block: int = 128,
-                  eps: float = 0.05, chunk: int = 8,
-                  load0: jnp.ndarray | None = None, m0: float = 0.0,
-                  interpret: bool | None = None):
-    """Snapshot-probing PoRC as a Pallas kernel — drop-in for
-    ``ref.ref_porc_snapshot`` (same signature, bit-identical result).
-
-    Every block probes the frozen VMEM load snapshot with its salted
-    chain (hashed in-kernel) against the capacity
-    (1+eps)·m_t/n_bins at block end; at block=1 the full 4·n_bins lazy
-    chain of Alg. 1 runs, so the kernel is bit-identical to the
-    sequential oracle. ``interpret=None`` → auto (compiled on TPU).
-
-    Returns (assignment [M] int32, final load [n_bins] f32).
-    """
-    M = keys.shape[0]
-    assert M % block == 0, f"{M} % {block} != 0"
-    n_blocks = M // block
-    load0_arr = (jnp.zeros((n_bins,), jnp.float32) if load0 is None
-                 else load0.astype(jnp.float32))
-    if n_blocks == 0:
-        return jnp.zeros((0,), jnp.int32), load0_arr
-    kernel = functools.partial(_snapshot_kernel, n_bins=n_bins, block=block,
-                               eps=eps, chunk=chunk, n_blocks=n_blocks)
-    m0_arr = jnp.reshape(jnp.asarray(m0, jnp.float32), (1,))
-    row = pl.BlockSpec((None, 1, block), lambda b: (b, 0, 0))
-    column = pl.BlockSpec((n_bins, 1), lambda b: (0, 0))
-    assign, load = pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), column, row],
-        out_specs=[row, column],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, 1, block), jnp.int32),
-            jax.ShapeDtypeStruct((n_bins, 1), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((n_bins, 1), jnp.float32)],
-        compiler_params=_SEQUENTIAL,
-        interpret=resolve_interpret(interpret),
-        name="porc_snapshot",
-    )(m0_arr, load0_arr.reshape(n_bins, 1), keys.reshape(n_blocks, 1, block))
-    return assign.reshape(M), load.reshape(n_bins)
-
-
-# ---------------------------------------------------------------------------
-# Multi-source — the ``_porc_multisource_scan`` kernel (delta + sketch
-# lanes in scratch, piggyback merge on the sync cadence)
+# The kernel — ``ref._porc_multisource_scan`` with the delta and sketch
+# lanes in scratch and the piggyback merge on the sync cadence
 # ---------------------------------------------------------------------------
 
 def _route_lanes(base, delta, kblk, *, n_bins: int, n_sources: int,
@@ -204,38 +127,9 @@ def _route_lanes(base, delta, kblk, *, n_bins: int, n_sources: int,
     return assign, counted
 
 
-def _route_lanes_hh(base, delta, skb, skd, kblk, *, n_bins: int,
-                    n_sources: int, block: int, eps: float, chunk_eff: int,
-                    policy: HHPolicy):
-    """The heavy-hitter policy counterpart of :func:`_route_lanes`
-    (interpret mode only: gathers, sorts and prefix sums). Works on the
-    jnp engine's row layout and converts back."""
-    S = n_sources
-    base, delta = base[:, 0], delta.T                  # [n], [S, n]
-    mass = blocks.count_sum(base) + blocks.count_sum(delta, 1)   # [S]
-    cap = blocks.view_cap(eps, n_bins, mass, block / S)
-    views = base[None, :] + delta                      # [S, n_bins]
-    cand = hash_to_bins(kblk[..., None], blocks.probe_salts(chunk_eff),
-                        n_bins)
-    est = jax.vmap(
-        lambda d, k: blocks.hh_sketch_query(policy, skb + d, k))(
-        skd, kblk)                                     # [S, block]
-    bud = blocks.hh_budgets(policy, n_bins, eps, est, mass[:, None])
-    assign = jax.vmap(
-        lambda view, c, kk, cb, bd: blocks.snapshot_block_hh(
-            view, c, kk, cb, bd, n_bins,
-            policy.rotate_duplicates, policy.spread_fallback))(
-        views, cap, kblk, cand, bud)
-    skd = jax.vmap(lambda d, k: blocks.hh_sketch_update(policy, d, k))(
-        skd, kblk)
-    delta = jax.vmap(lambda d, a: d.at[a].add(1.0))(delta, assign)
-    return assign, delta.T, skd
-
-
 def _multisource_kernel(*refs, n_bins: int, n_sources: int, block: int,
                         sync_every: int, eps: float, chunk: int,
-                        chunk_eff: int, n_blocks: int,
-                        policy: HHPolicy | None):
+                        n_blocks: int, policy: HHPolicy | None):
     S = n_sources
     if policy is None:
         (ticks_ref, base0_ref, delta0_ref, keys_ref,
@@ -264,10 +158,13 @@ def _multisource_kernel(*refs, n_bins: int, n_sources: int, block: int,
                                      n_sources=S, block=block, eps=eps,
                                      chunk=chunk)
     else:
+        # interpret mode only (gathers, sorts and prefix sums): the jnp
+        # engine's own block step, on its [S, n_bins] lane layout
         skb, skd = skb_scr[...], skd_scr[...]
-        assign, delta, skd = _route_lanes_hh(
-            base, delta, skb, skd, kblk, n_bins=n_bins, n_sources=S,
-            block=block, eps=eps, chunk_eff=chunk_eff, policy=policy)
+        assign, delta, skd = blocks.route_lanes(
+            base[:, 0], delta.T, skb, skd, kblk, None, n_bins=n_bins,
+            n_sources=S, block=block, eps=eps, chunk=chunk, policy=policy)
+        delta = delta.T
         skb = jnp.where(sync, skb + skd.sum(0), skb)
         skd = jnp.where(sync, jnp.zeros_like(skd), skd)
         skb_scr[...], skd_scr[...] = skb, skd
@@ -317,12 +214,10 @@ def porc_multisource_scan(keys: jnp.ndarray, n_bins: int, n_sources: int,
     nb = M // (S * block)
     # [nb, S, block]: source s's k-th message of its b-th block
     kb = keys.reshape(nb, block, S).transpose(0, 2, 1)
-    chunk_eff = (chunk if policy is None
-                 else blocks.hh_chunk(policy, chunk, n_bins))
     kernel = functools.partial(
         _multisource_kernel, n_bins=n_bins, n_sources=S, block=block,
-        sync_every=sync_every, eps=eps, chunk=chunk, chunk_eff=chunk_eff,
-        n_blocks=nb, policy=policy)
+        sync_every=sync_every, eps=eps, chunk=chunk, n_blocks=nb,
+        policy=policy)
     ticks_arr = jnp.reshape(jnp.asarray(ticks0, jnp.int32), (1,))
     column = pl.BlockSpec((n_bins, 1), lambda b: (0, 0))
     lanes = pl.BlockSpec((n_bins, S), lambda b: (0, 0))

@@ -1,14 +1,16 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the semantic ground truth the kernels are tested against
-(``tests/test_kernels_*.py`` sweeps shapes/dtypes and asserts
-equality / allclose).
+(``tests/test_kernels_*.py`` and ``tests/test_porc_snapshot_pallas.py``
+sweep shapes and assert equality / allclose).
 
 Oracles
 -------
-ref_porc_assign   block-synchronous PoRC (the TPU-adapted Alg. 1)
-ref_cg_dispatch   capacity-bounded MoE assignment with CG overflow
-ref_ssd_scan      Mamba-2 SSD recurrence (exact sequential scan)
+ref_porc_multisource  the PoRC block engine: S sources routing blocks
+                      against local load views (§V-C); one source is S=1
+ref_porc_route        its single-source form over ``PorcState``
+ref_cg_dispatch       capacity-bounded MoE assignment with CG overflow
+ref_ssd_scan          Mamba-2 SSD recurrence (exact sequential scan)
 """
 from __future__ import annotations
 
@@ -21,102 +23,26 @@ import jax.numpy as jnp
 from repro.core.hashing import hash_to_bins
 
 # The block-engine inner math lives in kernels/blocks.py so the jnp
-# reference engines here and the Pallas engines in porc_snapshot.py
+# reference engine here and the Pallas engine in porc_snapshot.py
 # consume literally the same implementation. Re-exported under the
-# historical names — every external import site says
-# ``from repro.kernels.ref import X`` and keeps working.
+# historical names — external import sites say
+# ``from repro.kernels.ref import X`` and keep working (view_cap too:
+# bench/faults.py's cap drift patches it on this module).
 from .blocks import (  # noqa: F401  (re-exports)
     HHPolicy,
-    SKETCH_SALT0 as _SKETCH_SALT0,
-    count_sum,
-    hh_budgets as _hh_budgets,
-    hh_chunk as _hh_chunk,
     hh_sketch_init,
     hh_sketch_query,
     hh_sketch_update,
     neutral_hh_policy,
     probe_salts,
-    salt_columns,
-    sketch_cols as _sketch_cols,
-    snapshot_block as _snapshot_block,
-    snapshot_block_hh as _snapshot_block_hh,
-    snapshot_cap,
+    route_lanes,
     view_cap,
 )
+from .porc_snapshot import porc_multisource_scan
 
-
-# ---------------------------------------------------------------------------
-# PoRC, block-synchronous semantics
-# ---------------------------------------------------------------------------
-
-def _porc_block(load, keys, cap, n_bins: int, d: int):
-    """Assign one block of keys against running loads.
-
-    Rank-sequential, key-vectorized: at rank r, every still-unassigned
-    key bids for its r-th salted choice H(key‖r+1); the first
-    ``cap − load`` bidders per bin (in block order) are accepted.
-    Ranks advance until every key is placed (Alg. 1's unbounded probe),
-    with a ceiling of d ranks; the rare leftovers are forced onto their
-    rank-d choice.
-    """
-    B = keys.shape[0]
-    assign = jnp.full((B,), -1, jnp.int32)
-    unassigned = jnp.ones((B,), bool)
-
-    def cond(carry):
-        r, load, assign, unassigned = carry
-        return (r < d) & jnp.any(unassigned)
-
-    def rank_step(carry):
-        r, load, assign, unassigned = carry
-        c = hash_to_bins(keys, (r + 1).astype(jnp.uint32), n_bins)
-        onehot = (c[:, None] == jnp.arange(n_bins)[None, :]) & unassigned[:, None]
-        pos = jnp.cumsum(onehot.astype(jnp.float32), axis=0) - onehot
-        mypos = jnp.take_along_axis(pos, c[:, None], axis=1)[:, 0]
-        accept = unassigned & (load[c] + mypos < cap)
-        assign = jnp.where(accept, c, assign)
-        load = load + jnp.sum(
-            onehot.astype(jnp.float32) * accept[:, None].astype(jnp.float32), axis=0)
-        return r + 1, load, assign, unassigned & ~accept
-
-    _, load, assign, unassigned = jax.lax.while_loop(
-        cond, rank_step, (jnp.int32(0), load, assign, unassigned))
-    # forced fallback at probe ceiling: spread leftovers round-robin over
-    # the least-loaded bins (the vectorized analogue of Alg. 1's
-    # argmin-load fallback; prevents pileup on any single bin).
-    order = jnp.argsort(load).astype(jnp.int32)
-    leftpos = jnp.cumsum(unassigned.astype(jnp.int32)) - 1
-    fallback = order[leftpos % n_bins]
-    assign = jnp.where(unassigned, fallback, assign)
-    forced = jnp.zeros((n_bins,), jnp.float32).at[fallback].add(
-        unassigned.astype(jnp.float32))
-    return load + forced, assign
-
-
-@functools.partial(jax.jit, static_argnames=("n_bins", "d", "block", "eps"))
-def ref_porc_assign(keys: jnp.ndarray, n_bins: int, *, d: int | None = None,
-                    block: int = 128, eps: float = 0.05,
-                    load0: jnp.ndarray | None = None,
-                    m0: float = 0.0):
-    """Oracle for kernels.porc_assign. keys length must be a multiple of
-    ``block``. Returns (assignment [M], final load [n_bins])."""
-    if d is None:
-        d = 4 * n_bins      # same probe ceiling as the sequential oracle
-    M = keys.shape[0]
-    assert M % block == 0
-    nb = M // block
-    kb = keys.reshape(nb, block)
-    load = jnp.zeros(n_bins, jnp.float32) if load0 is None else load0
-
-    def blk(load, xs):
-        b, keys_blk = xs
-        cap = snapshot_cap(eps, n_bins, m0, b, block)
-        load, assign = _porc_block(load, keys_blk, cap, n_bins, d)
-        return load, assign
-
-    load, assign = jax.lax.scan(blk, load,
-                                (jnp.arange(nb, dtype=jnp.float32), kb))
-    return assign.reshape(-1), load
+# the block engines a caller may name once ``backend.resolve_engine``
+# has resolved its knob
+ENGINES = ("ref", "pallas")
 
 
 # ---------------------------------------------------------------------------
@@ -124,14 +50,15 @@ def ref_porc_assign(keys: jnp.ndarray, n_bins: int, *, d: int | None = None,
 # ---------------------------------------------------------------------------
 
 class PorcState(NamedTuple):
-    """Routing state threaded across blocks, slots, and batches.
+    """Routing state of one source, threaded across blocks, slots, and
+    batches.
 
     ``load`` is the (eventually-consistent) per-bin message count and
-    ``routed`` the global message clock m_t that drives the capacity
-    (1+eps)·m_t/n — together they are everything Alg. 1 remembers.
-    ``sketch`` is the count-min heavy-hitter sketch that drives the
-    per-key probe depths when a :class:`HHPolicy` is active (``None``
-    otherwise — the default engine never materializes it).
+    ``routed`` the global message clock m_t — together they are
+    everything Alg. 1 remembers. ``sketch`` is the count-min
+    heavy-hitter sketch that drives the per-key probe depths when a
+    :class:`HHPolicy` is active (``None`` otherwise — the default engine
+    never materializes it).
 
     State-carry contract: every field continues across calls — splitting
     a stream over multiple ``ref_porc_route`` calls with the carried
@@ -176,60 +103,6 @@ def block_spans(m: int, block: int) -> list[tuple[int, int, int]]:
     return spans
 
 
-@functools.partial(jax.jit, static_argnames=("n_bins", "block", "eps", "chunk"))
-def ref_porc_snapshot(keys: jnp.ndarray, n_bins: int, *, block: int = 128,
-                      eps: float = 0.05, chunk: int = 8,
-                      load0: jnp.ndarray | None = None, m0: float = 0.0):
-    """Snapshot-probing PoRC: the block-parallel *fast path*.
-
-    Every message in a block independently walks its salted-probe chain
-    H(j‖1), H(j‖2), … against the load snapshot taken at the block
-    boundary and stops at the first bin below (1+eps)·m_t/n (m_t at
-    block end); loads update once per block. This is the paper's §V-C
-    eventual consistency — the same semantics as multiple sources
-    routing with local load views — so a bin can overshoot the capacity
-    by at most the number of duplicates of its keys inside one block.
-
-    Unlike the rank-sequential ``ref_porc_assign`` (which resolves
-    in-block contention rank by rank and therefore serializes ~max-key-
-    multiplicity steps per block), every probe here is a vectorized
-    gather, which is what makes the block path fast on CPU/TPU.
-
-    Probe budget: at block=1 the full 4·n_bins salted chain of Alg. 1
-    runs (lazily, in chunks of ``chunk`` salts) so the result is
-    bit-identical to the sequential oracle — the snapshot *is* the true
-    load. At block>1 each message gets a fixed budget of ``chunk``
-    probes per snapshot (hoisted out of the block scan entirely, since
-    they are load-independent); either way, exhausting the budget falls
-    back to the least-loaded snapshot bin, Alg. 1's fallback. A fixed
-    budget is the right trade at block>1 because a fresh snapshot
-    resolves ~everything within a few probes — paying a data-dependent
-    while-loop per block costs more than the rare deep chain saves.
-
-    Returns (assignment [M] int32, final load [n_bins] f32).
-    """
-    M = keys.shape[0]
-    assert M % block == 0, f"{M} % {block} != 0"
-    nb = M // block
-    kb = keys.reshape(nb, block)
-    load = jnp.zeros(n_bins, jnp.float32) if load0 is None else load0
-    # the first chunk of candidates is load-independent → hoist the
-    # hashing for the whole stream out of the per-block scan
-    salts0 = probe_salts(chunk)
-    cand0 = hash_to_bins(kb[:, :, None], salts0[None, None, :], n_bins)
-
-    def blk(load, xs):
-        b, kblk, cblk = xs
-        cap = snapshot_cap(eps, n_bins, m0, b, block)
-        assign = _snapshot_block(load, cap, kblk, salt_columns(cblk), n_bins,
-                                 block, chunk)
-        return load.at[assign].add(1.0), assign
-
-    load, assign = jax.lax.scan(blk, load,
-                                (jnp.arange(nb, dtype=jnp.float32), kb, cand0))
-    return assign.reshape(-1), load
-
-
 def route_in_spans(keys: jnp.ndarray, block: int, carry, step):
     """Drive a jitted block engine over ``block_spans`` of a stream.
 
@@ -248,31 +121,24 @@ def route_in_spans(keys: jnp.ndarray, block: int, carry, step):
 
 def ref_porc_route(keys: jnp.ndarray, n_bins: int, *, block: int = 128,
                    eps: float = 0.05, state: PorcState | None = None,
-                   engine: str = "snapshot",
+                   engine: str = "ref",
                    policy: HHPolicy | None = None):
-    """Route an arbitrary-length key stream in blocks of ``block``.
+    """Route an arbitrary-length key stream from one source.
 
-    ``engine="snapshot"`` (the fast path) probes block-boundary load
-    snapshots via ``ref_porc_snapshot``; ``engine="pallas"`` runs the
-    same semantics as the Pallas kernel
-    (``porc_snapshot.porc_snapshot`` — bit-identical, load in VMEM
-    scratch, compiled on TPU / interpreted elsewhere);
-    ``engine="strict"`` uses the rank-sequential ``ref_porc_assign``,
-    which never exceeds the (1+eps) cap but serializes in-block
-    contention (slower — use it when the ε guarantee must hold exactly,
-    e.g. tiny per-bin loads). The user-facing ``"ref"``/``"auto"``
-    spellings resolve to these via ``kernels.backend.resolve_engine``.
-    Either way a trailing partial block is routed as power-of-two
-    sub-blocks (caps at each sub-block end, bounded recompilation —
-    see ``block_spans``), so no padding keys ever pollute the load
-    state. With ``block=1`` both engines are bit-identical to the
-    sequential oracle ``partitioners.power_of_random_choices``.
+    One source is the multi-source engine at S=1: this maps ``state``
+    to a one-lane ``MultiSourcePorcState``, routes with
+    ``ref_porc_multisource`` and maps the result back. Every block
+    probes the load snapshot taken at its boundary against the capacity
+    (1+eps)·m_t/n at block end; a trailing partial block routes as
+    power-of-two sub-blocks (``block_spans``), so no padding keys ever
+    pollute the load state. ``engine`` is ``"ref"`` (the jnp scan) or
+    ``"pallas"`` (the Pallas kernel, bit-identical). With ``block=1``
+    and no policy the result is bit-identical to the sequential oracle
+    ``partitioners.power_of_random_choices``.
 
-    ``policy`` (snapshot engine only) turns on heavy-hitter-aware probe
-    depths — D/W-Choices, see :class:`HHPolicy` — with the count-min
-    sketch carried in ``state.sketch``; it routes through the
-    multi-source engine at S=1 (bit-identical framing, CI-gated for the
-    policy-free case). With a policy, ``block=1`` is *not* the
+    ``policy`` turns on heavy-hitter-aware probe depths — D/W-Choices,
+    see :class:`HHPolicy` — with the count-min sketch carried in
+    ``state.sketch``. With a policy, ``block=1`` is *not* the
     sequential oracle: the probe budget is policy-defined, not Alg. 1's
     4·n chain.
 
@@ -284,38 +150,13 @@ def ref_porc_route(keys: jnp.ndarray, n_bins: int, *, block: int = 128,
     """
     if state is None:
         state = porc_state_init(n_bins, policy)
-    if policy is not None:
-        if engine not in ("snapshot", "pallas"):
-            raise ValueError("HHPolicy requires the snapshot engine")
-        skb = state.sketch if state.sketch is not None \
-            else hh_sketch_init(policy)
-        ms = MultiSourcePorcState(
-            base=state.load,
-            delta=jnp.zeros((1, n_bins), jnp.float32),
-            routed=state.routed,
-            ticks=jnp.zeros((), jnp.int32),
-            sketch_base=skb,
-            sketch_delta=jnp.zeros((1,) + skb.shape, jnp.float32))
-        assign, ms = ref_porc_multisource(
-            keys, n_bins, 1, sync_every=1, block=block, eps=eps,
-            state=ms, engine=engine, policy=policy)
-        return assign, PorcState(
-            load=ms.base + ms.delta.sum(0), routed=ms.routed,
-            sketch=ms.sketch_base + ms.sketch_delta.sum(0))
-    if engine == "pallas":
-        from .porc_snapshot import porc_snapshot as eng  # deferred: pallas
-    else:
-        eng = {"snapshot": ref_porc_snapshot,
-               "strict": ref_porc_assign}[engine]
-
-    def step(sub, blk, carry):
-        load, routed = carry
-        a, load = eng(sub, n_bins, block=blk, eps=eps, load0=load, m0=routed)
-        return a, (load, routed + sub.shape[0])
-
-    assign, (load, routed) = route_in_spans(
-        keys, block, (state.load, state.routed), step)
-    return assign, PorcState(load=load, routed=routed)
+    assign, ms = ref_porc_multisource(
+        keys, n_bins, 1, block=block, eps=eps,
+        state=published_state(state.load, state.routed, 1, state.sketch),
+        engine=engine, policy=policy)
+    ms = multisource_merge(ms)
+    return assign, PorcState(load=ms.base, routed=ms.routed,
+                             sketch=ms.sketch_base)
 
 
 # ---------------------------------------------------------------------------
@@ -371,30 +212,89 @@ def multisource_state_init(n_bins: int, n_sources: int,
             (n_sources, policy.depth, policy.width), jnp.float32))
 
 
+def published_state(load, routed, n_sources: int,
+                    sketch=None) -> MultiSourcePorcState:
+    """The state of ``n_sources`` sources that have all published: the
+    merged ``load`` (and ``sketch``, when carried) with zero deltas, at
+    the start of a sync period."""
+    return MultiSourcePorcState(
+        base=load,
+        delta=jnp.zeros((n_sources,) + load.shape, jnp.float32),
+        routed=routed,
+        ticks=jnp.zeros((), jnp.int32),
+        sketch_base=sketch,
+        sketch_delta=None if sketch is None else jnp.zeros(
+            (n_sources,) + sketch.shape, jnp.float32))
+
+
+def lane_sum(delta, sync):
+    """The merge of vmapped source lanes: the sum of their deltas."""
+    return delta.sum(0)
+
+
+def scan_blocks(kb, base, delta, ticks0, skb, skd, *, n_bins: int,
+                n_sources: int, sync_every: int, block: int, eps: float,
+                chunk: int, policy: HHPolicy | None = None,
+                merge=lane_sum, weights=None):
+    """The multi-source block step, scanned over ``kb`` [nb, S', block].
+
+    Row ``kb[b, s]`` is lane s's b-th block. Per step every lane routes
+    its block against its local view ``base + delta[s]`` and counts it
+    into its delta (``blocks.route_lanes``); every ``sync_every`` steps
+    (phase continued from ``ticks0``) ``merge(delta, sync)`` — the
+    merged counts of every source — folds into the base and the deltas
+    reset, and so do the sketch lanes under a ``policy``. ``n_sources``
+    is the total S: the lanes here may be a shard of it
+    (``kernels.mesh`` passes a psum across hosts as ``merge``; the
+    vmapped engine the plain ``lane_sum``). ``weights`` [nb, S', block]
+    (default all ones) scales what each message counts.
+
+    With a policy the heavy/tail decision is one block stale, the same
+    staleness license as the load snapshot itself; ``policy=None``
+    traces to exactly the sketch-free engine.
+
+    Returns ``((base, delta, skb, skd), assign [nb, S', block])``.
+    """
+    # without a policy the candidates are load-independent: hash them
+    # for the whole stream ([nb, S', block, C]) outside the scan. The
+    # policy chain can be n_bins deep, so it hashes per block instead.
+    cand = (None if policy is not None
+            else hash_to_bins(kb[..., None], probe_salts(chunk), n_bins))
+
+    def blk(carry, xs):
+        base, delta, skb, skd = carry
+        b, kblk, cblk, w = xs
+        assign, delta, skd = route_lanes(
+            base, delta, skb, skd, kblk, cblk, n_bins=n_bins,
+            n_sources=n_sources, block=block, eps=eps, chunk=chunk,
+            policy=policy, weights=w)
+        # piggyback merge — phase continues from ticks0 across calls
+        sync = ((ticks0 + b + 1) % sync_every) == 0
+        base = jnp.where(sync, base + merge(delta, sync), base)
+        delta = jnp.where(sync, jnp.zeros_like(delta), delta)
+        if policy is not None:
+            skb = jnp.where(sync, skb + skd.sum(0), skb)
+            skd = jnp.where(sync, jnp.zeros_like(skd), skd)
+        return (base, delta, skb, skd), assign
+
+    return jax.lax.scan(
+        blk, (base, delta, skb, skd),
+        (jnp.arange(kb.shape[0], dtype=jnp.int32), kb, cand, weights))
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "n_bins", "n_sources", "sync_every", "block", "eps", "chunk", "engine",
-    "policy"))
+    "n_bins", "n_sources", "sync_every", "block", "eps", "chunk", "policy"))
 def _porc_multisource_scan(keys: jnp.ndarray, n_bins: int, n_sources: int,
                            sync_every: int, block: int, eps: float,
-                           chunk: int, engine: str, base0, delta0, ticks0,
+                           chunk: int, base0, delta0, ticks0,
                            skb0=None, skd0=None,
                            policy: HHPolicy | None = None):
-    """Core multi-source scan over full per-source blocks.
+    """Core multi-source scan over full per-source blocks (jnp).
 
     ``keys`` is the round-robin-interleaved global stream (message i
     belongs to source i % S); its length must be a multiple of S·block.
-    Per scan step every source routes one block of its substream against
-    ``base + delta[s]`` (``_snapshot_block`` or the rank-sequential
-    ``_porc_block``, vmapped over sources); every ``sync_every`` steps
-    the deltas merge into the base.
-
-    With a ``policy`` (snapshot engine only) each source additionally
-    classifies its block against its local sketch view at the block
-    boundary, routes with per-key probe budgets
-    (``_snapshot_block_hh``), and folds the block into its sketch delta
-    afterwards — so the heavy/tail decision is one block stale, the
-    same staleness license as the load snapshot itself. ``policy=None``
-    traces to exactly the sketch-free engine (bit-identical).
+    Same arguments and ``(assign, base, delta, ticks, skb, skd)`` return
+    as the Pallas ``porc_snapshot.porc_multisource_scan``.
     """
     S = n_sources
     M = keys.shape[0]
@@ -403,78 +303,10 @@ def _porc_multisource_scan(keys: jnp.ndarray, n_bins: int, n_sources: int,
     # [nb, S, block]: element [b, s, k] = keys[(b·block + k)·S + s],
     # source s's k-th message of its b-th block
     kb = keys.reshape(nb, block, S).transpose(0, 2, 1)
-    if engine == "snapshot":
-        chunk_eff = (chunk if policy is None
-                     else _hh_chunk(policy, chunk, n_bins))
-        salts0 = probe_salts(chunk_eff)
-        if policy is None:
-            cand0 = hash_to_bins(kb[..., None], salts0, n_bins)
-            xs_extra = (cand0,)             # [nb, S, block, C] hoisted
-        else:
-            # the policy chain can be n_bins deep — hash per block inside
-            # the scan instead of hoisting [nb, S, block, n_bins] for the
-            # whole stream
-            xs_extra = ()
-        route_block = jax.vmap(
-            lambda view, cap, kblk, cblk: _snapshot_block(
-                view, cap, kblk, salt_columns(cblk), n_bins, block, chunk),
-            in_axes=(0, 0, 0, 0))
-    else:        # "strict": in-block contention resolved rank by rank
-        assert policy is None, "HHPolicy requires the snapshot engine"
-        xs_extra = ()
-        route_block = jax.vmap(
-            lambda view, cap, kblk: _porc_block(
-                view, kblk, cap, n_bins, 4 * n_bins)[1],
-            in_axes=(0, 0, 0))
-
-    def blk(carry, xs):
-        base, delta, skb, skd = carry
-        b, kblk, *extra = xs
-        # Per-source capacity from the mass of its *local view* (merged
-        # base + own delta) — not the global clock. A cap the source
-        # cannot verify against its view would let all S sources fill a
-        # hot bin to the global cap independently (S× overshoot at cold
-        # start); the local-mass cap keeps the strict per-source
-        # invariant load_view ≤ (1+eps)·mass_view/n, whose sum
-        # telescopes to the global (1+eps)·m/n envelope — exactly why
-        # the paper's independent-sources argument works. The arriving
-        # block enters the mass as block/S so the *aggregate* lookahead
-        # across sources is one block, matching the single-source m_t
-        # (at S=1 this reduces bit-exactly to ``ref_porc_snapshot``'s
-        # capacity); a full +block per source would hand the S sources
-        # S·(1+eps)·block/n of joint slack on a shared hot bin.
-        mass = count_sum(base) + count_sum(delta, 1)      # [S] local view
-        cap = view_cap(eps, n_bins, mass, block / S)
-        views = base[None, :] + delta                     # [S, n_bins]
-        if policy is None:
-            assign = route_block(views, cap, kblk, *extra)   # [S, block]
-        else:
-            # heavy/tail classification against the block-boundary local
-            # sketch view, per-key budgets from the probe-depth schedule
-            cand = hash_to_bins(kblk[..., None], salts0, n_bins)
-            est = jax.vmap(lambda d, k: hh_sketch_query(policy, skb + d, k))(
-                skd, kblk)                                # [S, block]
-            bud = _hh_budgets(policy, n_bins, eps, est, mass[:, None])
-            assign = jax.vmap(
-                lambda view, c, kk, cblk, bd: _snapshot_block_hh(
-                    view, c, kk, cblk, bd, n_bins,
-                    policy.rotate_duplicates, policy.spread_fallback))(
-                views, cap, kblk, cand, bud)
-            skd = jax.vmap(lambda d, k: hh_sketch_update(policy, d, k))(
-                skd, kblk)
-        delta = jax.vmap(lambda d, a: d.at[a].add(1.0))(delta, assign)
-        # piggyback merge — phase continues from ticks0 across calls
-        sync = ((ticks0 + b + 1) % sync_every) == 0
-        base = jnp.where(sync, base + delta.sum(0), base)
-        delta = jnp.where(sync, jnp.zeros_like(delta), delta)
-        if policy is not None:
-            skb = jnp.where(sync, skb + skd.sum(0), skb)
-            skd = jnp.where(sync, jnp.zeros_like(skd), skd)
-        return (base, delta, skb, skd), assign
-
-    (base, delta, skb, skd), assign = jax.lax.scan(
-        blk, (base0, delta0, skb0, skd0),
-        (jnp.arange(nb, dtype=jnp.int32), kb, *xs_extra))
+    (base, delta, skb, skd), assign = scan_blocks(
+        kb, base0, delta0, ticks0, skb0, skd0, n_bins=n_bins, n_sources=S,
+        sync_every=sync_every, block=block, eps=eps, chunk=chunk,
+        policy=policy)
     # invert the round-robin interleave back to global message order
     return (assign.transpose(0, 2, 1).reshape(-1), base, delta,
             (ticks0 + nb) % sync_every, skb, skd)
@@ -498,120 +330,40 @@ def _porc_multisource_tail(keys_pad: jnp.ndarray, n_bins: int, n_sources: int,
     staleness bound.
     """
     S = n_sources
-    active = (jnp.arange(S) < n_tail)
-    chunk_eff = chunk if policy is None else _hh_chunk(policy, chunk, n_bins)
-    cand0 = hash_to_bins(keys_pad[:, None, None], probe_salts(chunk_eff),
-                         n_bins)
-    mass = count_sum(base0) + count_sum(delta0, 1)
-    cap = view_cap(eps, n_bins, mass, 1.0 / S)
-    if policy is None:
-        assign = jax.vmap(
-            lambda view, kblk, cblk, c: _snapshot_block(
-                view, c, kblk, salt_columns(cblk), n_bins, 1, chunk))(
-            base0[None, :] + delta0, keys_pad[:, None], cand0, cap)[:, 0]
-        skb, skd = skb0, skd0
-    else:
-        est = jax.vmap(
-            lambda d, k: hh_sketch_query(policy, skb0 + d, k))(
-            skd0, keys_pad[:, None])                       # [S, 1]
-        bud = _hh_budgets(policy, n_bins, eps, est, mass[:, None])
-        assign = jax.vmap(
-            lambda view, kk, cblk, c, bd: _snapshot_block_hh(
-                view, c, kk, cblk, bd, n_bins,
-                policy.rotate_duplicates, policy.spread_fallback))(
-            base0[None, :] + delta0, keys_pad[:, None], cand0, cap,
-            bud)[:, 0]
-        skd = jax.vmap(
-            lambda d, k, m: hh_sketch_update(policy, d, k, weights=m))(
-            skd0, keys_pad[:, None], active.astype(jnp.float32)[:, None])
-        skb = skb0 + skd.sum(0)
-        skd = jnp.zeros_like(skd)
-    delta = jax.vmap(lambda d, a, m: d.at[a].add(m))(
-        delta0, assign, active.astype(jnp.float32))
-    return assign, base0 + delta.sum(0), jnp.zeros_like(delta), skb, skd
+    active = (jnp.arange(S) < n_tail).astype(jnp.float32)
+    # one step of a block of one per lane; sync_every=1 publishes it
+    (base, delta, skb, skd), assign = scan_blocks(
+        keys_pad[None, :, None], base0, delta0, 0, skb0, skd0,
+        n_bins=n_bins, n_sources=S, sync_every=1, block=1, eps=eps,
+        chunk=chunk, policy=policy, weights=active[None, :, None])
+    return assign[0, :, 0], base, delta, skb, skd
 
 
-def ref_porc_multisource(keys: jnp.ndarray, n_bins: int, n_sources: int, *,
-                         sync_every: int = 1, block: int = 128,
-                         eps: float = 0.05, chunk: int = 8,
-                         state: MultiSourcePorcState | None = None,
-                         engine: str = "snapshot",
-                         policy: HHPolicy | None = None):
-    """Multi-source block-parallel PoRC (§V-C distributed sources).
+def route_multisource_spans(keys: jnp.ndarray, n_bins: int, n_sources: int,
+                            block: int, eps: float, chunk: int,
+                            state: MultiSourcePorcState, scan,
+                            policy: HHPolicy | None = None):
+    """The span-and-tail driver of the multi-source engines.
 
-    The stream splits round-robin across ``n_sources`` sources (message
-    i → source i % S, the paper's SG assignment of messages to sources);
-    each source routes blocks of ``block`` messages against its local
-    view ``base + own delta`` and the deltas merge into the shared base
-    every ``sync_every`` blocks (piggybacked synchronization). Staleness
-    is therefore bounded by one sync period: a source never misses more
-    than the other S−1 sources' ``sync_every·block`` most recent
-    messages.
+    The full per-source span routes as ``block_spans`` (full blocks,
+    then power-of-two sub-blocks), each through
+    ``scan(span_keys, engine_block, base, delta, ticks, skb, skd) ->
+    (assign, base, delta, ticks, skb, skd)``; a final sub-S ragged tail
+    routes one message per source through ``_porc_multisource_tail``
+    and publishes at once. ``state``'s sketch lanes must already match
+    ``policy``.
 
-    ``engine`` picks the per-block router, same choice as
-    ``ref_porc_route``: ``"snapshot"`` (the fast path — each block
-    probes a frozen local view), ``"pallas"`` (the same semantics as
-    the Pallas kernel ``porc_snapshot.porc_multisource_scan`` —
-    bit-identical, delta/sketch lanes in VMEM scratch; the ragged tail
-    and span driver below stay shared) or ``"strict"`` (rank-sequential
-    ``_porc_block`` — in-block contention resolved against the cap,
-    slower but exact inside a block; use it when per-bin loads are a
-    handful of messages, e.g. Fig 11's 100-source / 1000-VW point,
-    where one block of snapshot staleness would dominate the ε
-    mechanism).
-
-    With ``n_sources=1, sync_every=1`` the local view *is* the running
-    load, so the result is bit-identical to ``ref_porc_route`` with the
-    same engine (and at ``block=1`` to the sequential oracle). Arbitrary
-    stream lengths are handled like ``ref_porc_route``: the per-source
-    remainder routes as power-of-two sub-blocks (``block_spans``), and a
-    final sub-S ragged tail routes one message per source with the
-    others masked (and publishes immediately — see
-    ``_porc_multisource_tail``). The sync phase carries across spans and
-    calls via ``state.ticks`` (block-granular, so a stream fed in short
-    batches still merges every ``sync_every`` blocks); block boundaries
-    themselves realign per call, the same alignment caveat as
-    ``ref_porc_route``.
-
-    ``policy`` (snapshot engine only) turns on heavy-hitter-aware probe
-    depths (D/W-Choices): each source classifies keys against its local
-    count-min sketch view and probes with per-key budgets; the sketch
-    shards and delta-merges exactly like the load (see
-    :class:`HHPolicy`). ``policy=None`` — the default — is bit-identical
-    to the policy-free engine.
-
-    Returns (assignment [M] int32 in original stream order,
-    new MultiSourcePorcState).
+    Returns (assignment [M] int32 in stream order, new state).
     """
     S = n_sources
-    if engine not in ("snapshot", "strict", "pallas"):
-        raise ValueError(f"unknown engine {engine!r}")
-    if policy is not None and engine not in ("snapshot", "pallas"):
-        raise ValueError("HHPolicy requires the snapshot engine")
-    if state is None:
-        state = multisource_state_init(n_bins, S, policy)
     base, delta, routed, ticks, skb, skd = state
-    if policy is not None and skb is None:
-        # state predates the policy: start the sketch cold
-        skb = hh_sketch_init(policy)
-        skd = jnp.zeros((S, policy.depth, policy.width), jnp.float32)
-    if policy is None:
-        skb = skd = None                 # sketch is carried only with it
     per = keys.shape[0] // S             # full per-source span length
     r = keys.shape[0] - per * S
     parts = []
     off = 0
     for _, length, blk in block_spans(per, block):
-        span = keys[off: off + length * S]
-        if engine == "pallas":
-            from .porc_snapshot import porc_multisource_scan  # deferred
-            a, base, delta, ticks, skb, skd = porc_multisource_scan(
-                span, n_bins, S, sync_every, blk, eps, chunk,
-                base, delta, ticks, skb, skd, policy)
-        else:
-            a, base, delta, ticks, skb, skd = _porc_multisource_scan(
-                span, n_bins, S, sync_every, blk, eps, chunk, engine,
-                base, delta, ticks, skb, skd, policy)
+        a, base, delta, ticks, skb, skd = scan(
+            keys[off: off + length * S], blk, base, delta, ticks, skb, skd)
         routed = routed + length * S
         parts.append(a)
         off += length * S
@@ -631,6 +383,82 @@ def ref_porc_multisource(keys: jnp.ndarray, n_bins: int, n_sources: int, *,
     return assign, MultiSourcePorcState(base=base, delta=delta,
                                         routed=routed, ticks=ticks,
                                         sketch_base=skb, sketch_delta=skd)
+
+
+def ref_porc_multisource(keys: jnp.ndarray, n_bins: int, n_sources: int, *,
+                         sync_every: int = 1, block: int = 128,
+                         eps: float = 0.05, chunk: int = 8,
+                         state: MultiSourcePorcState | None = None,
+                         engine: str = "ref",
+                         policy: HHPolicy | None = None):
+    """Multi-source block-parallel PoRC (§V-C distributed sources).
+
+    The stream splits round-robin across ``n_sources`` sources (message
+    i → source i % S, the paper's SG assignment of messages to sources);
+    each source routes blocks of ``block`` messages against its local
+    view ``base + own delta`` and the deltas merge into the shared base
+    every ``sync_every`` blocks (piggybacked synchronization). Staleness
+    is therefore bounded by one sync period: a source never misses more
+    than the other S−1 sources' ``sync_every·block`` most recent
+    messages.
+
+    Each block probes its frozen local view: every message walks its
+    salted-probe chain H(j‖1), H(j‖2), … and stops at the first bin
+    below the source's capacity. At block=1 the full 4·n_bins chain of
+    Alg. 1 runs, so S=1, block=1 is bit-identical to the sequential
+    oracle ``partitioners.power_of_random_choices``; at block>1 each
+    message gets a fixed budget of ``chunk`` probes per snapshot.
+    Exhausting the budget falls back to the least-loaded view bin.
+
+    ``engine`` is ``"ref"`` (the jnp scan) or ``"pallas"`` (the Pallas
+    kernel ``porc_snapshot.porc_multisource_scan`` — bit-identical,
+    delta/sketch lanes in VMEM scratch; the ragged tail and span driver
+    stay shared); ``backend.resolve_engine`` maps the user-facing knob
+    to one of them.
+
+    Arbitrary stream lengths: the per-source remainder routes as
+    power-of-two sub-blocks (``block_spans``), and a final sub-S ragged
+    tail routes one message per source with the others masked (and
+    publishes immediately — see ``_porc_multisource_tail``). The sync
+    phase carries across spans and calls via ``state.ticks``
+    (block-granular, so a stream fed in short batches still merges
+    every ``sync_every`` blocks); block boundaries themselves realign
+    per call.
+
+    ``policy`` turns on heavy-hitter-aware probe depths (D/W-Choices):
+    each source classifies keys against its local count-min sketch view
+    and probes with per-key budgets; the sketch shards and delta-merges
+    exactly like the load (see :class:`HHPolicy`). ``policy=None`` — the
+    default — is bit-identical to the policy-free engine.
+
+    Returns (assignment [M] int32 in original stream order,
+    new MultiSourcePorcState).
+    """
+    S = n_sources
+    if engine not in ENGINES:
+        raise ValueError(f"unknown engine {engine!r}: expected one of "
+                         f"{ENGINES} (resolve 'auto' with "
+                         "kernels.resolve_engine)")
+    if state is None:
+        state = multisource_state_init(n_bins, S, policy)
+    if policy is None:
+        # the sketch is carried only with a policy
+        state = state._replace(sketch_base=None, sketch_delta=None)
+    elif state.sketch_base is None:
+        # state predates the policy: start the sketch cold
+        state = state._replace(
+            sketch_base=hh_sketch_init(policy),
+            sketch_delta=jnp.zeros((S, policy.depth, policy.width),
+                                   jnp.float32))
+    scan = porc_multisource_scan if engine == "pallas" \
+        else _porc_multisource_scan
+
+    def step(span, blk, *carry):
+        return scan(span, n_bins, S, sync_every, blk, eps, chunk, *carry,
+                    policy)
+
+    return route_multisource_spans(keys, n_bins, S, block, eps, chunk,
+                                   state, step, policy)
 
 
 def multisource_merge(state: MultiSourcePorcState) -> MultiSourcePorcState:

@@ -26,6 +26,7 @@ import numpy as np
 from repro import trace
 from repro.core import controller, delegation
 from repro.core.hashing import hash_to_bins
+from repro.kernels.backend import resolve_engine
 from repro.kernels.ref import (multisource_merge, multisource_state_init,
                                ref_porc_multisource)
 import jax.numpy as jnp
@@ -158,6 +159,7 @@ class CGRequestRouter:
                 headroom=self.hh_headroom)
         else:
             self._policy = None
+        self._engine = resolve_engine(self.engine, self._policy)
         self._state = multisource_state_init(self.n_virtual, self.n_sources,
                                              policy=self._policy)
         self._routed = 0
@@ -426,12 +428,11 @@ class CGRequestRouter:
     def _route_keys(self, keys):
         """Launch PoRC over ``keys`` from the current routing state:
         ``(assign_vw, new state)``. The mesh router routes on its mesh."""
-        from repro.kernels import resolve_engine
         return ref_porc_multisource(
             keys, self.n_virtual, self.n_sources,
             sync_every=self.sync_every, block=self.block_size,
             eps=self.eps, state=self._state, policy=self._policy,
-            engine=resolve_engine(self.engine, self._policy))
+            engine=self._engine)
 
     def finalize_batch(self, assign_vw) -> np.ndarray:
         """Admission half: bind a dispatched VW assignment to replicas

@@ -217,9 +217,14 @@ def test_multisource_policy_state_cold_start():
 
 
 def test_policy_rejects_strict_engine():
-    with pytest.raises(ValueError):
-        ref_porc_multisource(zipf_keys(1024), 16, 2, engine="strict",
-                             policy=HHPolicy())
+    """The kernels layer takes only a resolved engine: an unknown
+    spelling (the strict engine is gone) or an unresolved ``"auto"``
+    is rejected, with a policy or without."""
+    for engine in ("strict", "snapshot", "auto"):
+        for policy in (HHPolicy(), None):
+            with pytest.raises(ValueError, match="unknown engine"):
+                ref_porc_multisource(zipf_keys(1024), 16, 2, engine=engine,
+                                     policy=policy)
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,13 @@
 """Pallas snapshot-probing block engine vs the jnp oracles (interpret).
 
 The kernel (``kernels.porc_snapshot``) must be *bit-identical* to the
-jnp fast path — same assignments, same float load vectors, same sketch
-counters — because its block bodies call the very same ``kernels.blocks``
-math the ref engine uses. Everything here runs the kernel in interpret
+jnp engine — same assignments, same float load vectors, same sketch
+counters — because its block body calls the very same ``kernels.blocks``
+math the jnp scan uses. Everything here runs the kernel in interpret
 mode (the CI backend is CPU), which executes the kernel body with real
 JAX ops: parity here is the semantics gate for the compiled TPU path.
+One source is the kernel at S=1; the n_bins × block × S parity sweep is
+``tests/test_kernels_porc.py::test_kernel_matches_ref``.
 """
 import jax
 import jax.numpy as jnp
@@ -16,10 +18,9 @@ from repro.core import partitioners as P
 from repro.core import streams
 from repro.kernels.backend import resolve_engine, resolve_interpret
 from repro.kernels.blocks import HHPolicy, neutral_hh_policy
-from repro.kernels.porc_snapshot import porc_multisource_scan, porc_snapshot
+from repro.kernels.porc_snapshot import porc_multisource_scan
 from repro.kernels.ref import (multisource_state_init, porc_state_init,
-                               ref_porc_multisource, ref_porc_route,
-                               ref_porc_snapshot)
+                               ref_porc_multisource, ref_porc_route)
 
 
 def zipf_keys(m, z=1.3, n_keys=1000, seed=1):
@@ -27,41 +28,33 @@ def zipf_keys(m, z=1.3, n_keys=1000, seed=1):
 
 
 # ---------------------------------------------------------------------------
-# single source
+# single source (the kernel at S=1)
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("n_bins", [8, 100, 256])
-@pytest.mark.parametrize("block", [64, 128])
-def test_kernel_matches_snapshot_ref(n_bins, block):
-    keys = zipf_keys(4096)
-    a_ref, l_ref = ref_porc_snapshot(keys, n_bins, block=block, eps=0.05)
-    a_k, l_k = porc_snapshot(keys, n_bins, block=block, eps=0.05,
-                             interpret=True)
-    np.testing.assert_array_equal(np.asarray(a_ref), np.asarray(a_k))
-    # float loads must match *bit-exactly*: the kernel shares the ref's
-    # cap expression and accumulation order (blocks.snapshot_cap)
-    np.testing.assert_array_equal(np.asarray(l_ref), np.asarray(l_k))
-
 
 def test_kernel_b1_equals_sequential_oracle():
     """block=1 runs the full lazy probe chain — exact Alg. 1."""
     keys = zipf_keys(512)
     oracle = P.power_of_random_choices(keys, 32, eps=0.05)
-    a, _ = porc_snapshot(keys, 32, block=1, eps=0.05, interpret=True)
+    a, _ = ref_porc_multisource(keys, 32, 1, block=1, eps=0.05,
+                                engine="pallas")
     np.testing.assert_array_equal(np.asarray(oracle), np.asarray(a))
 
 
 def test_kernel_continuation_equals_one_shot():
-    """(m0, load0) carry across calls exactly like the ref."""
+    """The raw kernel at S=1 carries (base, delta) across calls."""
     n = 32
     keys = zipf_keys(2048, n_keys=500, z=1.2, seed=3)
-    a_full, l_full = porc_snapshot(keys, n, eps=0.05, interpret=True)
-    a1, l1 = porc_snapshot(keys[:1024], n, eps=0.05, interpret=True)
-    a2, l2 = porc_snapshot(keys[1024:], n, eps=0.05, load0=l1, m0=1024.0,
-                           interpret=True)
+    base, delta = jnp.zeros(n, jnp.float32), jnp.zeros((1, n), jnp.float32)
+    a_full, b_full, d_full, _, _, _ = porc_multisource_scan(
+        keys, n, 1, 1, 128, 0.05, 8, base, delta, 0, interpret=True)
+    a1, b1, d1, t1, _, _ = porc_multisource_scan(
+        keys[:1024], n, 1, 1, 128, 0.05, 8, base, delta, 0, interpret=True)
+    a2, b2, d2, _, _, _ = porc_multisource_scan(
+        keys[1024:], n, 1, 1, 128, 0.05, 8, b1, d1, t1, interpret=True)
     np.testing.assert_array_equal(np.asarray(a_full),
                                   np.concatenate([a1, a2]))
-    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l2))
+    np.testing.assert_array_equal(np.asarray(b_full), np.asarray(b2))
+    np.testing.assert_array_equal(np.asarray(d_full), np.asarray(d2))
 
 
 def test_route_engine_pallas_ragged_stream():
@@ -191,14 +184,13 @@ def test_multisource_scan_kernel_direct():
 
 def test_resolve_engine_mapping():
     on_tpu = jax.default_backend() == "tpu"
-    assert resolve_engine("ref") == "snapshot"
-    assert resolve_engine("jnp") == "snapshot"
-    assert resolve_engine("snapshot") == "snapshot"
-    assert resolve_engine("strict") == "strict"
+    assert resolve_engine("ref") == "ref"
     assert resolve_engine("pallas") == "pallas"
-    assert resolve_engine("auto") == ("pallas" if on_tpu else "snapshot")
-    with pytest.raises(ValueError):
-        resolve_engine("mosaic")
+    assert resolve_engine("auto") == ("pallas" if on_tpu else "ref")
+    assert resolve_engine("auto", HHPolicy()) == "ref"
+    for unknown in ("mosaic", "jnp", "snapshot", "strict"):
+        with pytest.raises(ValueError):
+            resolve_engine(unknown)
 
 
 def test_resolve_interpret_default():
@@ -218,3 +210,25 @@ def test_route_engine_validation():
     a_ref = P.route("PORC", keys, 16, block_size=64, engine="ref")
     a_k = P.route("PORC", keys, 16, block_size=64, engine="pallas")
     np.testing.assert_array_equal(np.asarray(a_ref), np.asarray(a_k))
+    a_auto = P.route("PORC", keys, 16, block_size=64, engine="auto")
+    np.testing.assert_array_equal(np.asarray(a_ref), np.asarray(a_auto))
+    for unknown in ("strict", "snapshot"):
+        with pytest.raises(ValueError, match="unknown engine"):
+            P.route("PORC", keys, 16, block_size=64, engine=unknown)
+
+
+def test_config_surfaces_reject_unknown_engine():
+    """``CGConfig`` and ``CGRequestRouter`` resolve their ``engine`` at
+    the boundary: a spelling the resolver does not know fails there."""
+    from repro.core import cg
+    from repro.serve import CGRequestRouter, MeshCGRequestRouter
+    keys = zipf_keys(256)
+    cfg = cg.CGConfig(n_workers=2, alpha=4, slot_len=128, block_size=64,
+                      engine="strict")
+    with pytest.raises(ValueError, match="unknown engine"):
+        cg.run(cfg, keys, jnp.ones(2))
+    for router in (CGRequestRouter, MeshCGRequestRouter):
+        with pytest.raises(ValueError, match="unknown engine"):
+            router(n_replicas=2, engine="strict")
+    assert CGRequestRouter(n_replicas=2, engine="ref").route_batch(
+        np.asarray(keys)).shape == (256,)

@@ -9,7 +9,7 @@ pytest.importorskip(
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import metrics, partitioners as P
-from repro.kernels.ref import ref_cg_dispatch, ref_porc_assign
+from repro.kernels.ref import ref_cg_dispatch, ref_porc_route
 
 SETTINGS = dict(max_examples=20, deadline=None)
 
@@ -30,11 +30,15 @@ def test_porc_capacity_invariant(n_bins, seed, eps):
 @given(st.integers(2, 32), st.integers(0, 2**31 - 1))
 @settings(**SETTINGS)
 def test_kernel_equals_ref_random(n_bins, seed):
+    """The Pallas block engine (interpret mode) equals the jnp scan at
+    random bin counts."""
     keys = jax.random.randint(jax.random.PRNGKey(seed), (512,), 0, 200)
-    from repro.kernels.porc_assign import porc_assign
-    a_ref, l_ref = ref_porc_assign(keys, n_bins, block=128, eps=0.05)
-    a_k, l_k = porc_assign(keys, n_bins, block=128, eps=0.05)
+    a_ref, s_ref = ref_porc_route(keys, n_bins, block=128, eps=0.05)
+    a_k, s_k = ref_porc_route(keys, n_bins, block=128, eps=0.05,
+                              engine="pallas")
     np.testing.assert_array_equal(np.asarray(a_ref), np.asarray(a_k))
+    np.testing.assert_array_equal(np.asarray(s_ref.load),
+                                  np.asarray(s_k.load))
 
 
 @given(st.integers(0, 2**31 - 1), st.integers(1, 4),

@@ -24,7 +24,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 from repro.core import cg
 from repro.kernels import backend
 from repro.kernels import mesh as kmesh
-from repro.kernels.porc_snapshot import porc_multisource_scan, porc_snapshot
+from repro.kernels.porc_snapshot import porc_multisource_scan
 
 N_BINS = 240          # 24 workers x 10 virtual workers
 BLOCK = 128
@@ -73,23 +73,17 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-def test_porc_snapshot_compiles(one_chip, chip_compile):
-    compiled = jax.jit(lambda k, l: porc_snapshot(
-        k, N_BINS, block=BLOCK, eps=EPS, load0=l, m0=1.0e6,
-        interpret=False)).lower(
-        _shape((M,), jnp.int32, one_chip),
-        _shape((N_BINS,), jnp.float32, one_chip)).compile()
-    assert _has_kernel(compiled)
-
-
-@pytest.mark.parametrize("sync_every", [1, 4])
-def test_porc_multisource_scan_compiles(one_chip, chip_compile, sync_every):
+@pytest.mark.parametrize("n_sources,sync_every", [(S, 1), (S, 4), (1, 1)])
+def test_porc_multisource_scan_compiles(one_chip, chip_compile, n_sources,
+                                        sync_every):
+    """The block kernel at the Storm deployment's 8 lanes, and at one
+    source (S=1, single-source routing)."""
     compiled = jax.jit(lambda k, b, d, t: porc_multisource_scan(
-        k, N_BINS, S, sync_every, BLOCK, EPS, 8, b, d, t,
+        k, N_BINS, n_sources, sync_every, BLOCK, EPS, 8, b, d, t,
         interpret=False)).lower(
         _shape((M,), jnp.int32, one_chip),
         _shape((N_BINS,), jnp.float32, one_chip),
-        _shape((S, N_BINS), jnp.float32, one_chip),
+        _shape((n_sources, N_BINS), jnp.float32, one_chip),
         _shape((), jnp.int32, one_chip)).compile()
     assert _has_kernel(compiled)
 
